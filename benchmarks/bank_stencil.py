@@ -42,6 +42,7 @@ from repro.core import (
     plan_cache_stats,
     unmelt,
 )
+from repro.runtime.compile_cache import place_compile_cache
 
 TARGET_SPEEDUP = 2.0
 RANK = 3
@@ -108,6 +109,7 @@ def main(argv=None):
                          "runners; the no-materialize assertion and "
                          "crashes always exit nonzero)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     shape = QUICK_SHAPE if args.quick else FULL_SHAPE
     reps = 5 if args.quick else 15

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import clear_plan_cache, gaussian_filter, plan_cache_stats
+from repro.runtime.compile_cache import place_compile_cache
 
 #: the acceptance config: paper-faithful path, B=8, dispatch-bound tile size
 #: (batching amortizes per-call dispatch; tiny tiles are where a serving
@@ -70,6 +71,7 @@ def main(argv=None):
                          "target (off by default: wall-clock gates flake on "
                          "shared runners; crashes always exit nonzero)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     reps = 10 if args.quick else 30
     cases = [HEADLINE]

@@ -49,6 +49,7 @@ from repro.core import (
 )
 from repro.core.filters import difference_stencils, gaussian_weights
 from repro.pipe import pipe
+from repro.runtime.compile_cache import place_compile_cache
 from repro.stats import moments
 
 TARGET_SPEEDUP = 2.0
@@ -155,6 +156,7 @@ def main(argv=None):
                          "runners; the no-materialize assertion and "
                          "crashes always exit nonzero)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     shape = QUICK_SHAPE if args.quick else FULL_SHAPE
     reps = 3 if args.quick else 7

@@ -2,7 +2,9 @@
 
 Prints ``name,us_per_call,derived`` CSV (harness contract); ``--json PATH``
 additionally writes machine-readable results (name, us_per_call, derived,
-backend, git rev per row) for the BENCH_*.json trajectory.
+backend, git rev per row) for the BENCH_*.json trajectory.  A section
+that raises still prints (and writes) its ``ERROR`` row, and the run
+then exits 1.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--json PATH]
                                            [--sections a,b,...]
@@ -31,6 +33,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.runtime.compile_cache import place_compile_cache
 
 
 def _time(f, *args, reps=5, warmup=2):
@@ -242,6 +246,7 @@ def main(argv=None):
                          "fig6,fig7,stencil,filters,bank,stats,pipe,"
                          "tiled,model,serve-lm,serve")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     from benchmarks import paper_figs
 
@@ -269,13 +274,15 @@ def main(argv=None):
         sections = {k: sections[k] for k in wanted}
     print("name,us_per_call,derived")
     per_section = {}
+    failed = []
     for name_sec, sec in sections.items():
         try:
             rows = sec()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — the row records it; exit 1
             import traceback
             traceback.print_exc()
             rows = [("ERROR", 0.0, str(e))]
+            failed.append(name_sec)
         for name, us, derived in rows:
             print(f"{name},{us:.1f},{derived}")
             sys.stdout.flush()
@@ -290,6 +297,8 @@ def main(argv=None):
         for name_sec, rows in per_section.items():
             write_json(os.path.join(args.json_dir,
                                     f"BENCH_{name_sec}.json"), rows)
+    if failed:
+        sys.exit(f"sections failed: {', '.join(failed)}")
     return all_rows
 
 

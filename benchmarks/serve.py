@@ -42,6 +42,7 @@ import jax
 import numpy as np
 
 from repro.pipe import pipe
+from repro.runtime.compile_cache import place_compile_cache
 from repro.serve import MemoryBudget, PipeService, ServeConfig
 
 TARGET_SPEEDUP = 2.0
@@ -231,6 +232,7 @@ def main(argv=None):
                          "runners; the bit-identity and zero-shed "
                          "assertions always exit nonzero)")
     args = ap.parse_args(argv)
+    place_compile_cache()
     reps = 7 if args.quick else 11
 
     rows, speedup = headline_rows(reps)

@@ -36,6 +36,7 @@ import numpy as np
 
 from benchmarks.bank_stencil import _time, _time_pair
 from repro.core import clear_plan_cache, melt_call_count, plan_cache_stats
+from repro.runtime.compile_cache import place_compile_cache
 from repro.stats import (
     channel_cov,
     histogram,
@@ -121,6 +122,7 @@ def main(argv=None):
                          "runners; the no-materialize assertion and "
                          "crashes always exit nonzero)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     item = QUICK_ITEM if args.quick else FULL_ITEM
     reps = 5 if args.quick else 15

@@ -77,6 +77,7 @@ from repro.core import (
 )
 from repro.core.filters import difference_stencils, gaussian_weights
 from repro.pipe import pipe
+from repro.runtime.compile_cache import place_compile_cache
 from repro.stats import moments
 from repro.stats.moments import merge_moments
 
@@ -297,6 +298,7 @@ def main(argv=None):
                          "runners; the contract assertions always exit "
                          "nonzero)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     shape = QUICK_SHAPE if args.quick else FULL_SHAPE
     reps = 3 if args.quick else 5

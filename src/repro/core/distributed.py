@@ -28,7 +28,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.grid import make_quasi_grid, normalize_pad_value
 from repro.core.engine import apply_stencil
@@ -173,9 +172,9 @@ def sharded_stencil_fn(
         spec = P(batch_axis_name, axis_name, *([None] * (rank - 1)))
     else:
         spec = P(axis_name, *([None] * (rank - 1)))
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -315,9 +314,9 @@ def sharded_pipe_fn(
         out_spec = P(*(tuple(in_spec) + (None,)))
     else:
         out_spec = in_spec
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -344,7 +343,8 @@ def put_tile_batch(batch, mesh: Mesh, axis_name: str):
     """Place a host-side stacked tile batch onto the mesh, stack-sharded.
 
     The stack extent must divide the mesh axis (the tiled scheduler groups
-    tiles in multiples of the axis size; ragged remainders run unsharded).
+    tiles in multiples of the axis size; ragged remainders run one tile
+    per device).
     """
     n = batch.shape[0]
     ways = mesh.shape[axis_name]
@@ -438,9 +438,9 @@ def sharded_moments_fn(
         return state
 
     spec = _stats_in_spec(ndim, axis_name, batch_axis_name)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec,), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -473,9 +473,9 @@ def sharded_histogram_fn(
         return Histogram(jax.lax.psum(h.counts, names), lo, hi)
 
     spec = _stats_in_spec(ndim, axis_name, batch_axis_name)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec,), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -249,12 +249,20 @@ def execute_separable_bank(x, grid: QuasiGrid, factors, pad_value,
         return make_quasi_grid(cur_shape, op1, s1, grid.padding,
                                grid.dilation)
 
-    g = grid1(0, grid.in_shape)
-    out = execute_stencil_bank(x, g, factors[0], pad_value, method, batched)
+    grids = [grid1(0, grid.in_shape)]
     for i in range(1, rank):
-        g = grid1(i, g.out_shape)
-        out = execute_stencil_depthwise(out, g, factors[i], pad_value,
-                                        method, batched)
+        grids.append(grid1(i, grids[-1].out_shape))
+    if method == "fused":
+        from repro.kernels import melt_stencil_ops  # lazy: kernels optional
+
+        return melt_stencil_ops.fused_separable_bank(
+            x, tuple(grids), tuple(factors),
+            pad_value=normalize_pad_value(pad_value), batched=batched)
+    out = execute_stencil_bank(x, grids[0], factors[0], pad_value, method,
+                               batched)
+    for g, f in zip(grids[1:], factors[1:]):
+        out = execute_stencil_depthwise(out, g, f, pad_value, method,
+                                        batched)
     return out
 
 

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _bilateral_kernel(x_ref, lsp_ref, o_ref, *, offsets: Tuple[int, ...],
@@ -28,7 +29,7 @@ def _bilateral_kernel(x_ref, lsp_ref, o_ref, *, offsets: Tuple[int, ...],
     base = i * tile_rows
     cols = []
     for off in offsets:
-        cols.append(pl.load(x_ref, (pl.ds(base + off, tile_rows), slice(None)))
+        cols.append(x_ref[pl.ds(base + off, tile_rows), :]
                     .astype(jnp.float32))
     tile = jnp.stack(cols, axis=-1)[:, 0, :]  # (T, numel) melt tile in VMEM
     center = tile[:, center_idx][:, None]
@@ -67,7 +68,8 @@ def bilateral_rows(x_halo: jax.Array, log_spatial: jax.Array, row_offsets,
         kernel,
         grid=(tiles,),
         in_specs=[
-            pl.BlockSpec(block_shape=None),
+            # whole input in VMEM: this kernel serves small volumes only
+            pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec((1, lsp.shape[1]), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((tile_rows, 1), lambda i: (i, 0)),

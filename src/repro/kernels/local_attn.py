@@ -9,8 +9,8 @@ row.  Kernel structure:
       j ∈ {i - W/T, …, i};    # the melt-row halo
       online-softmax accumulate (f32 m/l/acc), masked by causal+window.
 
-q/k/v arrive as whole-array refs; kv tiles stream via ``pl.ds`` (DMA on
-real TPUs).  MXU-aligned when dh and T are multiples of 128.  Requires
+q/k/v arrive as whole arrays in VMEM (short sequences only); kv tiles are
+sliced with ``pl.ds``.  MXU-aligned when dh and T are multiples of 128.  Requires
 W % T == 0, S % T == 0.
 """
 from __future__ import annotations
@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -30,7 +31,7 @@ def _local_attn_kernel(q_ref, k_ref, v_ref, o_ref, *, tile: int, window: int,
                        scale: float):
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    q = pl.load(q_ref, (bh, pl.ds(qi * tile, tile), slice(None)))  # (T, dh)
+    q = q_ref[bh, pl.ds(qi * tile, tile), :]  # (T, dh)
     q = q.astype(jnp.float32) * scale
     dh = q.shape[-1]
     n_kv_tiles = window // tile + 1  # halo tiles + own tile
@@ -44,8 +45,8 @@ def _local_attn_kernel(q_ref, k_ref, v_ref, o_ref, *, tile: int, window: int,
         j = qi - (n_kv_tiles - 1) + t  # kv tile index (may be < 0)
         start = j * tile
         safe = jnp.maximum(start, 0)
-        k = pl.load(k_ref, (bh, pl.ds(safe, tile), slice(None)))
-        v = pl.load(v_ref, (bh, pl.ds(safe, tile), slice(None)))
+        k = k_ref[bh, pl.ds(safe, tile), :]
+        v = v_ref[bh, pl.ds(safe, tile), :]
         k_pos = safe + jax.lax.iota(jnp.int32, tile)
         valid = (start >= 0) & (q_pos[:, None] >= k_pos[None, :]) & \
                 (q_pos[:, None] - k_pos[None, :] < window)
@@ -66,8 +67,7 @@ def _local_attn_kernel(q_ref, k_ref, v_ref, o_ref, *, tile: int, window: int,
         )
         m = m_new
     out = acc / jnp.maximum(l[:, None], 1e-30)
-    pl.store(o_ref, (bh, pl.ds(qi * tile, tile), slice(None)),
-             out.astype(o_ref.dtype))
+    o_ref[bh, pl.ds(qi * tile, tile), :] = out.astype(o_ref.dtype)
 
 
 def local_attention(q, k, v, window: int, *, tile: int = 128,
@@ -80,11 +80,13 @@ def local_attention(q, k, v, window: int, *, tile: int = 128,
     qf, kf, vf = fold(q), fold(k), fold(v)
     kernel = functools.partial(_local_attn_kernel, tile=tile, window=window,
                                scale=scale)
+    # whole arrays in VMEM: this kernel serves short sequences only
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,
         grid=(B * H, S // tile),
-        in_specs=[pl.BlockSpec(block_shape=None)] * 3,
-        out_specs=pl.BlockSpec(block_shape=None),
+        in_specs=[vmem] * 3,
+        out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((B * H, S, dh), q.dtype),
         interpret=interpret,
     )(qf, kf, vf)

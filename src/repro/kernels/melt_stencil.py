@@ -1,33 +1,47 @@
-"""Fused melt×contract Pallas kernel — the TPU-native melt matrix.
+"""Fused melt×contract Pallas kernels — the TPU-native melt matrix.
 
 DESIGN.md §2: the paper materializes the melt matrix ``M`` (rows = grid
 points, cols = operator elements) in memory and broadcasts over it.  On TPU
-that inflates HBM traffic by ``numel(m)``; this kernel instead builds each
-*tile* of melt rows in VMEM from shifted slices of a halo-extended input
-slab and contracts with the operator ravel vector on the fly — ``M`` never
+that inflates HBM traffic by ``numel(m)``; these kernels instead build each
+tile of melt rows in VMEM from shifted slices of halo-extended input
+windows and contract with the operator weights on the fly — ``M`` never
 exists in HBM.
 
-Canonicalization: any rank-k stride-1 stencil — 'same' or 'valid', the
-wrapper's output crop is the only difference (``ops._valid_slices``) —
-flattens to a 2-D problem (R, C): R = prod(leading grid dims), C =
-trailing (lane) dim, and a static per-operator-element *row offset* table
-derived from ``QuasiGrid.flat_offsets`` — the offset table carries all
-the geometry, so one kernel serves every rank.  Each output tile i reads input rows
-``[i·T, i·T + T + halo_lo + halo_hi)`` (the §2.4 slab + halo) and computes
-``Σ_c w_c · slab[c_off : c_off + T]`` on the VPU; multi-channel variants
-feed the MXU via an (T, numel) × (numel, C) contraction.
+Canonical layout (lane-dense).  Any rank-k stride-1 stencil — 'same' or
+'valid', the wrapper's output crop is the only difference
+(``ops._valid_slices``) — is computed at every position of the padded,
+flattened volume, with a static per-operator-element *flat offset* table
+derived from ``QuasiGrid.flat_offsets``: one rule for every rank.  The
+flat volume of each (batch item, channel) is viewed as ``(rows, 128)``,
+so flat position ``p`` sits at row ``p // 128``, lane ``p % 128``.  A tap
+at (non-negative, halo-shifted) flat offset ``o = 128·q + r`` reads rows
+``q`` and ``q + 1`` of the input window, rolls both by ``r`` along the
+lanes, and selects by ``lane < 128 − r``.
 
-The input arrives as a whole-array ref (HBM); slices are pulled with
-``pl.ds`` — on real TPUs these lower to DMA copies into VMEM, in interpret
-mode they execute directly.  Validated against ``ref.py`` (materialized
-melt) over shape/dtype sweeps in tests/test_kernels.py.
+Memory.  The input stays in HBM (``memory_space=pl.ANY``).  Each grid step
+``(b, c, i)`` copies the input rows its output tile ``[i·T, (i+1)·T)``
+reads into a VMEM scratch buffer: one DMA per *window*, where a window is
+a run of taps whose rows overlap (a 3×3×3 operator over a volume needs
+three windows, one per z-plane, instead of one slab spanning two whole
+planes of halo).  The copy is synchronous; the tile is then swept in
+8-row chunks so the accumulators stay in vector registers.  Weights live
+in SMEM as scalars.
 
-Operator banks (DESIGN.md §9): the ``*_bank_*`` variants contract each
-melt tile against a (numel, K) weight *matrix* — the (T, numel) × (numel, K)
-MXU contraction — so one slab pass serves K operators; the ``*_depthwise_*``
-variants filter lane k with weight column k (the separable 1-D pass
-primitive).  ``pick_tile_rows`` sizes tiles from a VMEM budget instead of a
-fixed constant.
+Families.  One kernel serves all three linear families: input
+``(B, C, rows, 128)``, ``kper`` outputs per input channel, weights
+``(numel, C·kper)``:
+
+- stencil   — C = 1, kper = 1;
+- bank      — C = 1, kper = K: one window pass feeds K operators
+  (DESIGN.md §9);
+- depthwise — C = K, kper = 1: channel k is filtered by weight column k
+  (the separable 1-D pass primitive).
+
+The moment kernel (statistics engine, DESIGN.md §10) reduces blocked
+``(T, W)`` row tiles of a ``(B, rows, W)`` input.
+``pick_tile_rows`` sizes tiles from a VMEM budget; validated against the
+materialized melt and ``lax`` in tests/test_kernels.py, and compiled for
+the chip in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -35,16 +49,25 @@ import functools
 import os
 import threading
 import time
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-#: default VMEM working-set target per grid step (well under the ~16 MB/core
-#: budget: the pipeline keeps two steps in flight plus the weight block)
-DEFAULT_VMEM_BUDGET = 2 * 1024 * 1024
+#: lane width of one TPU vector register; the canonical row width
+LANES = 128
+
+#: rows per inner chunk — one f32 vreg (8 sublanes × 128 lanes)
+_CHUNK = 8
+
+#: default VMEM working-set target per grid step (input windows plus the
+#: double-buffered output block); the kernels request ``_VMEM_LIMIT`` of
+#: scoped VMEM, which leaves the compiler headroom above this
+DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+_VMEM_LIMIT = 32 * 1024 * 1024
 
 #: min sublane count per dtype itemsize (TPU tiling: (sublane, 128) tiles =
 #: 32 bytes of sublanes per lane, so sublanes = 32 // itemsize; itemsize 8
@@ -52,43 +75,54 @@ DEFAULT_VMEM_BUDGET = 2 * 1024 * 1024
 #: falling through a silent default)
 _SUBLANES = {8: 4, 4: 8, 2: 16, 1: 32}
 
+#: largest tile, in 128-lane rows
+_MAX_TILE_ROWS = 1024
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
+
 
 def pick_tile_rows(numel: int, c_in: int, c_out: int, dtype,
-                   vmem_budget: Optional[int] = None) -> int:
-    """Choose ``tile_rows`` from a VMEM budget (sublane-aligned heuristic).
+                   vmem_budget: Optional[int] = None, windows: int = 1,
+                   span_rows: int = 0) -> int:
+    """Choose ``tile_rows`` (128-lane rows per grid step) from a VMEM budget.
 
-    Per output row the kernel holds ~``4·(numel + c_out)`` bytes of f32
-    working set (the assembled melt tile / accumulator plus the output tile)
-    and reads ``itemsize·c_in`` bytes of input slab; on top of that every
-    grid step stages the ``4·numel·c_out``-byte f32 weight block, which is
-    independent of ``tile_rows`` and comes off the budget before the rows
-    divide it up (a big bank otherwise overshoots VMEM by the whole block).
-    ``tile_rows`` is the largest sublane-aligned row count whose working
-    set fits ``vmem_budget``, clamped to [sublane, 1024] so tiny operators
-    never explode the grid and huge banks never starve it.
+    Each grid step holds ``windows`` input windows of ``tile_rows`` rows
+    plus their static spans (``span_rows`` in all, taken off the budget
+    before the rows divide it up), and a double-buffered output block of
+    ``c_out // c_in`` channels.  Rows are f32 in VMEM whatever the input
+    dtype (the wrappers compute in f32).  ``numel`` does not enter: taps
+    are swept one at a time and the weights sit in SMEM.  ``tile_rows`` is
+    the largest sublane-aligned row count whose working set fits
+    ``vmem_budget``, clamped to [sublane, 1024] so tiny problems never
+    explode the grid and wide banks never overrun VMEM.
     """
+    del numel
     budget = DEFAULT_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)
-    item = jnp.dtype(dtype).itemsize
-    sub = _SUBLANES.get(item, 8)
-    numel, c_in, c_out = int(numel), max(int(c_in), 1), max(int(c_out), 1)
-    per_row = 4 * (numel + c_out) + item * c_in
-    t = ((budget - 4 * numel * c_out) // per_row // sub) * sub
-    return int(max(sub, min(t, 1024)))
+    sub = max(_SUBLANES.get(jnp.dtype(dtype).itemsize, 8), _CHUNK)
+    kper = max(int(c_out), 1) // max(int(c_in), 1) or 1
+    row = LANES * 4
+    per_row = row * (int(windows) + 2 * kper)
+    fixed = row * (int(span_rows) + 2 * _CHUNK * int(windows))
+    t = ((budget - fixed) // per_row // sub) * sub
+    return int(max(sub, min(t, _MAX_TILE_ROWS)))
 
 
 # -- measured tile autotuning (DESIGN.md §16) --------------------------------
 #
-# ``tile_rows=None`` used to mean "the pick_tile_rows heuristic"; it now
-# means *measured*: time a few sublane-aligned candidates around the
-# heuristic on a synthetic canonical problem, intern the winner as a
-# ``TunePlan`` in the shared plan LRU (one measurement per key, hits
-# thereafter), and fall back to the heuristic when the opt-out env pins it.
-# Measurement timings are hardware facts, not plan state, so they also
-# live in a process-lifetime memo — a ``clear_plan_cache()`` re-interns
-# the TunePlan from the memo instead of re-timing the kernels.
+# ``tile_rows=None`` means *measured*: time a few sublane-aligned
+# candidates around the heuristic on a synthetic canonical problem,
+# intern the winner as a ``TunePlan`` in the shared plan LRU (one
+# measurement per key, hits thereafter), and fall back to the heuristic
+# when the opt-out env pins it.  Measurement timings are hardware facts,
+# not plan state, so they also live in a process-lifetime memo — a
+# ``clear_plan_cache()`` re-interns the TunePlan from the memo instead of
+# re-timing the kernels.  Whatever the source, the wrapper caps the tile
+# at what fits VMEM for the call's own window geometry.
 #
-# ``fused_moment_rows`` deliberately keeps the plain heuristic: its tile
-# size shapes the Chan merge tree's numerics and must mirror
+# ``fused_moment_rows`` deliberately keeps a fixed tile: its tile size
+# shapes the Chan merge tree's numerics and must mirror
 # ``moment_tile_counts`` exactly, so a measured (cache-dependent) size
 # would change results and break the static count mirror.
 
@@ -109,10 +143,10 @@ def _tile_candidates(numel: int, c_in: int, c_out: int, dtype
                      ) -> Tuple[int, ...]:
     """Sublane-aligned candidate set bracketing the heuristic (¼×–2×)."""
     base = pick_tile_rows(numel, c_in, c_out, dtype)
-    sub = _SUBLANES.get(jnp.dtype(dtype).itemsize, 8)
+    sub = max(_SUBLANES.get(jnp.dtype(dtype).itemsize, 8), _CHUNK)
     cands = []
     for t in (base // 4, base // 2, base, 2 * base):
-        t = max(sub, min((t // sub) * sub, 1024))
+        t = max(sub, min((t // sub) * sub, _MAX_TILE_ROWS))
         if t not in cands:
             cands.append(t)
     return tuple(cands)
@@ -122,52 +156,28 @@ def _measure_candidates(family: str, numel: int, c_in: int, c_out: int,
                         dtype, candidates: Tuple[int, ...]) -> list:
     """Wall-time each candidate on a synthetic canonical problem (µs).
 
-    The synthetic block is a few grid steps at the largest candidate —
-    big enough that the per-step slab/tile shape (what ``tile_rows``
-    controls) dominates, small enough that first-use tuning stays
-    a few kernel compiles.  One warm-up call per candidate absorbs the
-    compile; the min of the timed reps is the score.
+    The synthetic block is a few grid steps at the largest candidate, in
+    the kernels' own ``(B, C, flat)`` layout with one window of ``numel``
+    consecutive taps — big enough that the per-step tile shape (what
+    ``tile_rows`` controls) dominates, small enough that first-use tuning
+    stays a few kernel compiles.  One warm-up call per candidate absorbs
+    the compile; the min of the timed reps is the score.
     """
-    interpret = jax.default_backend() != "tpu"
-    halo = numel - 1
-    rows = 2 * max(candidates)
-    dt = jnp.dtype(dtype)
-    w_col = jnp.full((numel,), 1.0 / numel, jnp.float32)
-    w_mat = jnp.full((numel, c_out), 1.0 / numel, jnp.float32)
-    offs = tuple(range(numel))
-
-    def synth(lanes: int):
-        n = (rows + halo) * lanes
-        return (jnp.arange(n, dtype=jnp.float32) % 7.0).astype(dt).reshape(
-            rows + halo, lanes)
-
-    if family == "stencil":
-        x = synth(c_in)
-
-        def call(a, tile_rows):
-            return fused_stencil_rows(a, w_col, offs, rows, 0,
-                                      tile_rows=tile_rows,
-                                      interpret=interpret)
-    elif family == "bank":
-        x = synth(1)
-
-        def call(a, tile_rows):
-            return fused_stencil_bank_rows(a, w_mat, offs, rows, 0,
-                                           tile_rows=tile_rows,
-                                           interpret=interpret)
-    elif family == "depthwise":
-        x = synth(c_out)
-
-        def call(a, tile_rows):
-            return fused_stencil_rows_depthwise(a, w_mat, offs, rows, 0,
-                                                tile_rows=tile_rows,
-                                                interpret=interpret)
-    else:  # pragma: no cover — families are fixed by the entry points
+    if family not in ("stencil", "bank", "depthwise"):
         raise ValueError(f"unknown tune family {family!r}")
+    interpret = jax.default_backend() != "tpu"
+    c_in = 1 if family != "depthwise" else c_in
+    out_len = 2 * max(candidates) * LANES
+    w = jnp.full((numel, c_out), 1.0 / numel, jnp.float32)
+    offs = tuple(range(numel))
+    x = (jnp.arange(c_in * out_len, dtype=jnp.float32) % 7.0).astype(
+        dtype).reshape(1, c_in, out_len)
 
     timings = []
     for cand in candidates:
-        f = jax.jit(functools.partial(call, tile_rows=cand))
+        f = jax.jit(functools.partial(
+            fused_melt_rows, weights=w, offsets=offs, tile_rows=cand,
+            interpret=interpret, family=family))
         f(x).block_until_ready()  # compile + warm-up
         best = float("inf")
         for _ in range(2):
@@ -182,8 +192,10 @@ def tuned_tile_rows(family: str, numel: int, c_in: int, c_out: int,
                     dtype) -> int:
     """The measured ``tile_rows`` for one canonical kernel problem.
 
-    Keyed ``(backend, family, numel, c_in, c_out, dtype)`` and interned as
-    a :class:`~repro.core.plan.TunePlan` in the shared plan LRU: the first
+    ``c_in`` counts input channels and ``c_out`` output channels (stencil
+    1/1, bank 1/K, depthwise K/K).  Keyed ``(backend, family, numel,
+    c_in, c_out, dtype)`` and interned as a
+    :class:`~repro.core.plan.TunePlan` in the shared plan LRU: the first
     request times the :func:`_tile_candidates` set and memoizes the
     winner; every later request (and every re-intern after a cache clear)
     is a lookup.  With ``REPRO_TILE_AUTOTUNE=0`` (or an explicit
@@ -232,432 +244,239 @@ def tuned_tile_rows(family: str, numel: int, c_in: int, c_out: int,
     return get_tune_plan(key, build).tile_rows
 
 
-def _stencil_kernel(x_ref, w_ref, o_ref, *, offsets: Tuple[int, ...],
-                    tile_rows: int):
-    i = pl.program_id(0)
-    base = i * tile_rows  # x is pre-padded by halo_lo at the front
-    acc = jnp.zeros(o_ref.shape, jnp.float32)
-    for c, off in enumerate(offsets):
-        sl = pl.load(x_ref, (pl.ds(base + off, tile_rows), slice(None)))
-        acc = acc + w_ref[c, 0].astype(jnp.float32) * sl.astype(jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
+# -- the linear melt kernel --------------------------------------------------
 
 
-def fused_stencil_rows(x_halo: jax.Array, weights: jax.Array,
-                       row_offsets, out_rows: int, halo_lo: int,
-                       tile_rows: Optional[int] = None,
-                       interpret: bool = True):
-    """2-D canonical form.
+def plan_windows(offsets: Sequence[int], tile_rows: int):
+    """Static DMA plan for one output tile of ``tile_rows`` rows.
 
-    x_halo: (out_rows + halo_lo + halo_hi, C) — input rows with halo padding.
-    row_offsets: per operator element, row shift in [-halo_lo, +halo_hi].
-    Returns (out_rows, C).
+    ``offsets`` are non-negative flat offsets.  Returns ``(taps,
+    windows)``: ``taps[t] = (row, shift)`` locates tap ``t`` in the VMEM
+    scratch (its first row, relative to the output row, and its lane
+    roll); ``windows`` lists ``(src_row, dst_row, nrows)`` copies, each
+    8-row aligned, relative to the tile's first row.  Taps whose rows
+    overlap share one window.
     """
-    R, C = out_rows, x_halo.shape[1]
-    if tile_rows is None:
-        tile_rows = tuned_tile_rows("stencil", len(row_offsets), C, C,
-                                    x_halo.dtype)
-    tiles = -(-R // tile_rows)
-    pad_r = tiles * tile_rows + (x_halo.shape[0] - R) - x_halo.shape[0]
-    if pad_r > 0:
-        x_halo = jnp.pad(x_halo, ((0, pad_r), (0, 0)))
-    w2 = weights.reshape(-1, 1).astype(jnp.float32)
-    # shift offsets to be relative to the slab start (all ≥ 0)
-    offs = tuple(int(o) + halo_lo for o in np.asarray(row_offsets))
+    qs = sorted({int(o) // LANES for o in offsets})
+    spans = []  # [q_lo, q_hi] per window
+    for q in qs:
+        if spans and q <= spans[-1][1] + 1 + tile_rows:
+            spans[-1][1] = q
+        else:
+            spans.append([q, q])
+    windows, where, dst = [], {}, 0
+    for q_lo, q_hi in spans:
+        src = (q_lo // _CHUNK) * _CHUNK
+        n = _round_up(q_hi + 1 + tile_rows, _CHUNK) - src
+        windows.append((src, dst, n))
+        for q in qs:
+            if q_lo <= q <= q_hi:
+                where[q] = dst + q - src
+        dst += n
+    taps = tuple((where[int(o) // LANES], int(o) % LANES) for o in offsets)
+    return taps, tuple(windows)
 
-    kernel = functools.partial(_stencil_kernel, offsets=offs,
-                               tile_rows=tile_rows)
+
+def _melt_kernel(w_ref, x_hbm, o_ref, slab, sems, *, taps, windows,
+                 tile_rows: int, kper: int, wcols: int):
+    b, c, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    base = pl.multiple_of(i * tile_rows, _CHUNK)
+    copies = [
+        pltpu.make_async_copy(x_hbm.at[b, c, pl.ds(base + src, n), :],
+                              slab.at[pl.ds(dst, n), :], sems.at[k])
+        for k, (src, dst, n) in enumerate(windows)
+    ]
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+    lane = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, LANES), 1)
+    wbase = c * kper
+
+    def chunk(j, carry):
+        r0 = pl.multiple_of(j * _CHUNK, _CHUNK)
+        accs = [jnp.zeros((_CHUNK, LANES), jnp.float32)] * kper
+        for t, (row, shift) in enumerate(taps):
+            v = slab[pl.ds(r0 + row, _CHUNK), :]
+            if shift:
+                nxt = slab[pl.ds(r0 + row + 1, _CHUNK), :]
+                v = jnp.where(lane < LANES - shift,
+                              pltpu.roll(v, LANES - shift, 1),
+                              pltpu.roll(nxt, LANES - shift, 1))
+            for k in range(kper):
+                accs[k] = accs[k] + w_ref[t * wcols + wbase + k] * v
+        for k in range(kper):
+            o_ref[0, k, pl.ds(r0, _CHUNK), :] = accs[k]
+        return carry
+
+    jax.lax.fori_loop(0, tile_rows // _CHUNK, chunk, 0)
+
+
+def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
+                    tile_rows: Optional[int] = None,
+                    interpret: bool = True, family: str = "bank"):
+    """The canonical linear melt pass, every family.
+
+    x: ``(B, C, P)`` flat padded volumes, ``P`` a multiple of 128 (whole
+    rows of the ``(rows, 128)`` view).  Output position ``p`` of input
+    channel ``c`` sums ``x[b, c, p + offsets[t]]`` over taps ``t``, reading
+    zeros outside ``[0, P)``; weights ``(numel, C·kper)`` give output
+    channel ``c·kper + k`` the weight ``weights[t, c·kper + k]`` on tap
+    ``t``.  Returns ``(B, C·kper, P)`` float32.  ``tile_rows=None`` is
+    measured per ``family`` (:func:`tuned_tile_rows`); any tile is capped
+    at what fits the VMEM budget for this call's window geometry.
+    """
+    B, C, P = x.shape
+    offsets = tuple(int(o) for o in offsets)
+    numel, wcols = weights.shape
+    if numel != len(offsets) or wcols % C:
+        raise ValueError(f"weights {weights.shape} do not match "
+                         f"{len(offsets)} taps over {C} channels")
+    if P % LANES:
+        raise ValueError(f"flat volumes must fill whole {LANES}-lane rows, "
+                         f"got {P} positions")
+    kper = wcols // C
+    # the front halo in whole rows, so both pads below are row pads of
+    # the (rows, 128) view
+    front = -(-max(0, -min(offsets)) // LANES)
+    shifted = tuple(o + front * LANES for o in offsets)
+    if tile_rows is None:
+        tile_rows = tuned_tile_rows(family, numel, C, wcols, x.dtype)
+    out_rows = P // LANES
+    T = max(_CHUNK, min(_round_up(tile_rows, _CHUNK),
+                        _round_up(out_rows, _CHUNK)))
+    taps, windows = plan_windows(shifted, T)
+    spans = sum(n - T for _, _, n in windows)
+    cap = pick_tile_rows(numel, C, wcols, jnp.float32,
+                         windows=len(windows), span_rows=spans)
+    if T > cap:
+        T = cap
+        taps, windows = plan_windows(shifted, T)
+    tiles = -(-out_rows // T)
+    in_rows = (tiles - 1) * T + max(src + n for src, _, n in windows)
+    slab_rows = sum(n for _, _, n in windows)
+    # one pad: the front halo, then zeros up to whole input rows (the
+    # back halo lies inside them, since every window stays in range)
+    xf = jnp.pad(x.astype(jnp.float32).reshape(B, C, out_rows, LANES),
+                 ((0, 0), (0, 0), (front, in_rows - front - out_rows),
+                  (0, 0)))
+    kernel = functools.partial(_melt_kernel, taps=taps, windows=windows,
+                               tile_rows=T, kper=kper, wcols=wcols)
     out = pl.pallas_call(
         kernel,
-        grid=(tiles,),
+        grid=(B, C, tiles),
         in_specs=[
-            pl.BlockSpec(block_shape=None),          # whole array (HBM ref)
-            pl.BlockSpec((w2.shape[0], 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # weights as scalars
+            pl.BlockSpec(memory_space=pl.ANY),      # input stays in HBM
         ],
-        out_specs=pl.BlockSpec((tile_rows, C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((tiles * tile_rows, C), x_halo.dtype),
+        out_specs=pl.BlockSpec((1, kper, T, LANES),
+                               lambda b, c, i: (b, c, i, 0)),
+        # exactly the rows P needs: the last block may overhang, and its
+        # overhang is dropped
+        out_shape=jax.ShapeDtypeStruct((B, wcols, out_rows, LANES),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((slab_rows, LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((len(windows),))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(x_halo, w2)
-    return out[:R]
-
-
-def _stencil_kernel_batched(x_ref, w_ref, o_ref, *, offsets: Tuple[int, ...],
-                            tile_rows: int):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    base = i * tile_rows
-    acc = jnp.zeros(o_ref.shape[1:], jnp.float32)  # (tile_rows, C)
-    for c, off in enumerate(offsets):
-        sl = pl.load(x_ref, (b, pl.ds(base + off, tile_rows), slice(None)))
-        acc = acc + w_ref[c, 0].astype(jnp.float32) * sl.astype(jnp.float32)
-    o_ref[...] = acc[None].astype(o_ref.dtype)
-
-
-def fused_stencil_rows_batched(x_halo: jax.Array, weights: jax.Array,
-                               row_offsets, out_rows: int, halo_lo: int,
-                               tile_rows: Optional[int] = None,
-                               interpret: bool = True):
-    """Batched 2-D canonical form: one grid axis per batch item.
-
-    x_halo: (B, out_rows + halo_lo + halo_hi, C) — each item's rows with its
-    own halo padding (items never read across the batch boundary).
-    Returns (B, out_rows, C).
-    """
-    B, _, C = x_halo.shape
-    R = out_rows
-    if tile_rows is None:
-        tile_rows = tuned_tile_rows("stencil", len(row_offsets), C, C,
-                                    x_halo.dtype)
-    tiles = -(-R // tile_rows)
-    pad_r = tiles * tile_rows + (x_halo.shape[1] - R) - x_halo.shape[1]
-    if pad_r > 0:
-        x_halo = jnp.pad(x_halo, ((0, 0), (0, pad_r), (0, 0)))
-    w2 = weights.reshape(-1, 1).astype(jnp.float32)
-    offs = tuple(int(o) + halo_lo for o in np.asarray(row_offsets))
-
-    kernel = functools.partial(_stencil_kernel_batched, offsets=offs,
-                               tile_rows=tile_rows)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, tiles),
-        in_specs=[
-            pl.BlockSpec(block_shape=None),          # whole array (HBM ref)
-            pl.BlockSpec((w2.shape[0], 1), lambda b, i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_rows, C), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, tiles * tile_rows, C),
-                                       x_halo.dtype),
-        interpret=interpret,
-    )(x_halo, w2)
-    return out[:, :R]
-
-
-# -- operator banks ---------------------------------------------------------
-#
-# The multi-output form promised by the module docstring: each output tile
-# computes the (tile_rows, numel) × (numel, K) melt-tile contraction, so the
-# halo slab load is amortized across all K operators and ``M`` still never
-# exists in HBM.  Two mathematically identical formulations, chosen by the
-# static ``mxu`` flag:
-#
-# - ``mxu=True``  (TPU): assemble the melt tile in VMEM and issue ONE
-#   ``jnp.dot`` — the MXU-shaped contraction.
-# - ``mxu=False`` (interpret/CPU): the same contraction unrolled over the
-#   numel axis as outer-product accumulates — interpret-mode concatenate is
-#   ~3x the cost of the whole tile otherwise, so the unrolled form is what
-#   makes the CPU proof representative.
-#
-# Default: ``mxu = not interpret``.
-
-
-def _bank_tile(x_ref, w_ref, offsets, base, tile_rows, K, mxu, lead=()):
-    """One (tile_rows, K) output tile of the bank contraction."""
-    if mxu:
-        cols = [
-            pl.load(x_ref,
-                    lead + (pl.ds(base + off, tile_rows), slice(None)))
-            .reshape(tile_rows, -1)
-            for off in offsets
-        ]
-        tile = jnp.concatenate(cols, axis=1).astype(jnp.float32)
-        return jnp.dot(tile, w_ref[...].astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
-    acc = jnp.zeros((tile_rows, K), jnp.float32)
-    for c, off in enumerate(offsets):
-        sl = pl.load(x_ref,
-                     lead + (pl.ds(base + off, tile_rows), slice(None)))
-        acc = acc + sl.reshape(tile_rows, -1).astype(jnp.float32) \
-            * w_ref[c, :][None, :].astype(jnp.float32)
-    return acc
-
-
-def _bank_kernel(x_ref, w_ref, o_ref, *, offsets: Tuple[int, ...],
-                 tile_rows: int, mxu: bool):
-    i = pl.program_id(0)
-    acc = _bank_tile(x_ref, w_ref, offsets, i * tile_rows, tile_rows,
-                     o_ref.shape[-1], mxu)
-    o_ref[...] = acc.astype(o_ref.dtype)
-
-
-def fused_stencil_bank_rows(x_halo: jax.Array, weight_matrix: jax.Array,
-                            row_offsets, out_rows: int, halo_lo: int,
-                            tile_rows: Optional[int] = None,
-                            interpret: bool = True,
-                            mxu: Optional[bool] = None):
-    """Bank 2-D canonical form: K operators over one slab pass.
-
-    x_halo: (out_rows + halo_lo + halo_hi, 1) — canonical single-lane rows.
-    weight_matrix: (numel, K) — one column per operator.
-    Returns (out_rows, K).
-    """
-    R = out_rows
-    numel, K = weight_matrix.shape
-    if tile_rows is None:
-        tile_rows = tuned_tile_rows("bank", numel, x_halo.shape[1], K,
-                                    x_halo.dtype)
-    if mxu is None:
-        mxu = not interpret
-    tiles = -(-R // tile_rows)
-    pad_r = tiles * tile_rows - R
-    if pad_r > 0:
-        x_halo = jnp.pad(x_halo, ((0, pad_r), (0, 0)))
-    W = weight_matrix.astype(jnp.float32)
-    offs = tuple(int(o) + halo_lo for o in np.asarray(row_offsets))
-
-    kernel = functools.partial(_bank_kernel, offsets=offs,
-                               tile_rows=tile_rows, mxu=mxu)
-    out = pl.pallas_call(
-        kernel,
-        grid=(tiles,),
-        in_specs=[
-            pl.BlockSpec(block_shape=None),          # whole array (HBM ref)
-            pl.BlockSpec((numel, K), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_rows, K), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((tiles * tile_rows, K), x_halo.dtype),
-        interpret=interpret,
-    )(x_halo, W)
-    return out[:R]
-
-
-def _bank_kernel_batched(x_ref, w_ref, o_ref, *, offsets: Tuple[int, ...],
-                         tile_rows: int, mxu: bool):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    acc = _bank_tile(x_ref, w_ref, offsets, i * tile_rows, tile_rows,
-                     o_ref.shape[-1], mxu, lead=(b,))
-    o_ref[...] = acc[None].astype(o_ref.dtype)
-
-
-def fused_stencil_bank_rows_batched(x_halo: jax.Array,
-                                    weight_matrix: jax.Array,
-                                    row_offsets, out_rows: int, halo_lo: int,
-                                    tile_rows: Optional[int] = None,
-                                    interpret: bool = True,
-                                    mxu: Optional[bool] = None):
-    """Batched bank form: grid (B, tiles), each item its own halo rows.
-
-    x_halo: (B, out_rows + halo_lo + halo_hi, 1).  Returns (B, out_rows, K).
-    """
-    B = x_halo.shape[0]
-    R = out_rows
-    numel, K = weight_matrix.shape
-    if tile_rows is None:
-        tile_rows = tuned_tile_rows("bank", numel, x_halo.shape[2], K,
-                                    x_halo.dtype)
-    if mxu is None:
-        mxu = not interpret
-    tiles = -(-R // tile_rows)
-    pad_r = tiles * tile_rows - R
-    if pad_r > 0:
-        x_halo = jnp.pad(x_halo, ((0, 0), (0, pad_r), (0, 0)))
-    W = weight_matrix.astype(jnp.float32)
-    offs = tuple(int(o) + halo_lo for o in np.asarray(row_offsets))
-
-    kernel = functools.partial(_bank_kernel_batched, offsets=offs,
-                               tile_rows=tile_rows, mxu=mxu)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, tiles),
-        in_specs=[
-            pl.BlockSpec(block_shape=None),          # whole array (HBM ref)
-            pl.BlockSpec((numel, K), lambda b, i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_rows, K), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, tiles * tile_rows, K),
-                                       x_halo.dtype),
-        interpret=interpret,
-    )(x_halo, W)
-    return out[:, :R]
-
-
-# -- depthwise (per-lane) form ---------------------------------------------
-#
-# Separable factorization executes a bank as successive 1-D passes; after
-# the first pass the K bank outputs live in lanes, and each lane owns its
-# own 1-D factor.  The depthwise kernel is the per-lane weighted melt: a
-# VPU broadcast-multiply per tap, no cross-lane contraction.
-
-
-def _depthwise_kernel(x_ref, w_ref, o_ref, *, offsets: Tuple[int, ...],
-                      tile_rows: int):
-    i = pl.program_id(0)
-    base = i * tile_rows
-    acc = jnp.zeros(o_ref.shape, jnp.float32)
-    for c, off in enumerate(offsets):
-        sl = pl.load(x_ref, (pl.ds(base + off, tile_rows), slice(None)))
-        acc = acc + w_ref[c, :][None, :].astype(jnp.float32) * sl.astype(
-            jnp.float32)
-    o_ref[...] = acc.astype(o_ref.dtype)
-
-
-def fused_stencil_rows_depthwise(x_halo: jax.Array, weights: jax.Array,
-                                 row_offsets, out_rows: int, halo_lo: int,
-                                 tile_rows: Optional[int] = None,
-                                 interpret: bool = True):
-    """Per-lane 2-D canonical form.
-
-    x_halo: (out_rows + halo_lo + halo_hi, K) — K independent channels in
-    lanes.  weights: (numel, K) — lane k is filtered by column k.
-    Returns (out_rows, K).
-    """
-    R = out_rows
-    numel, K = weights.shape
-    if tile_rows is None:
-        tile_rows = tuned_tile_rows("depthwise", numel, K, K, x_halo.dtype)
-    tiles = -(-R // tile_rows)
-    pad_r = tiles * tile_rows - R
-    if pad_r > 0:
-        x_halo = jnp.pad(x_halo, ((0, pad_r), (0, 0)))
-    W = weights.astype(jnp.float32)
-    offs = tuple(int(o) + halo_lo for o in np.asarray(row_offsets))
-
-    kernel = functools.partial(_depthwise_kernel, offsets=offs,
-                               tile_rows=tile_rows)
-    out = pl.pallas_call(
-        kernel,
-        grid=(tiles,),
-        in_specs=[
-            pl.BlockSpec(block_shape=None),          # whole array (HBM ref)
-            pl.BlockSpec((numel, K), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_rows, K), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((tiles * tile_rows, K), x_halo.dtype),
-        interpret=interpret,
-    )(x_halo, W)
-    return out[:R]
+    )(weights.astype(jnp.float32).reshape(-1), xf)
+    return out.reshape(B, wcols, P)
 
 
 # -- tile moment reduction (statistics engine, DESIGN.md §10) ---------------
 #
 # The statistics engine's sufficient statistics are mergeable per-tile
-# reductions over the SAME canonical (rows × lanes) layout the stencil
-# kernels stream — each grid step loads one row tile into VMEM and emits
-# that tile's (Σx, Σ(x−x̄)², Σ(x−x̄)³, Σ(x−x̄)⁴) per lane, so the melt matrix
-# never exists in HBM and the input is read exactly once.  The power sums
-# are *tile-centered* (about the tile's own masked mean): raw Σx²…Σx⁴
-# cancel catastrophically in f32 once |mean| ≫ std, while centered sums
-# bound the cancellation to one tile; the Chan merge tree downstream
-# combines tiles without ever forming a global raw sum (DESIGN.md §10).
-# Rows past ``valid_rows`` (tile padding) are masked out of both the pivot
-# mean and the sums; per-tile counts are static host-side knowledge.
+# reductions over ``(B, R, W)`` row blocks: each grid step reads one
+# ``(T, W)`` row tile into VMEM (a blocked spec: no halo, so the
+# pipeline's own DMA suffices) and emits that tile's (Σx, Σ(x−x̄)²,
+# Σ(x−x̄)³, Σ(x−x̄)⁴) per column, so the melt matrix never exists in HBM
+# and the input is read exactly once.  ``W`` is the full row width —
+# the reduced values' own trailing axis, or 128 for flat-packed values —
+# so a volume reaches the kernel by a reshape that keeps its minor axis.
+# The power sums are *tile-centered* (about the tile's own masked mean):
+# raw Σx²…Σx⁴ cancel catastrophically in f32 once |mean| ≫ std, while
+# centered sums bound the cancellation to one tile; the Chan merge tree
+# downstream combines tiles and columns without ever forming a global raw
+# sum (DESIGN.md §10).  Rows past ``valid_rows`` (the last tile's
+# overhang) are masked out of both the pivot mean and the sums; per-tile
+# counts are static host-side knowledge.
+
+#: elements per moment tile at 128-lane width (a 256 KiB f32 block)
+MOMENT_TILE_ELEMS = 512 * LANES
+
+
+def _moment_tile(num_rows: int, width: int, tile_rows: Optional[int]) -> int:
+    if tile_rows is None:
+        tile_rows = MOMENT_TILE_ELEMS // _round_up(width, LANES)
+    return max(_CHUNK, min(_round_up(tile_rows, _CHUNK),
+                           _round_up(max(int(num_rows), 1), _CHUNK)))
 
 
 def _moment_kernel(x_ref, o_ref, *, tile_rows: int, valid_rows: int,
                    order: int):
-    i = pl.program_id(0)
-    sl = pl.load(x_ref, (pl.ds(i * tile_rows, tile_rows), slice(None)))
-    sl = sl.astype(jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_rows, 1), 0)
-    mask = (rows < valid_rows - i * tile_rows).astype(jnp.float32)
-    n = jnp.clip(valid_rows - i * tile_rows, 1, tile_rows).astype(jnp.float32)
-    sl = sl * mask
-    s1 = jnp.sum(sl, axis=0)
-    c = (sl - (s1 / n)[None, :]) * mask  # centered about the tile pivot
+    i = pl.program_id(1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, x_ref.shape[1:], 0)
+    valid = rows < valid_rows - i * tile_rows
+    n = jnp.clip(valid_rows - i * tile_rows, 1, tile_rows).astype(
+        jnp.float32)
+    sl = jnp.where(valid, x_ref[0].astype(jnp.float32), 0.0)
+    s1 = jnp.sum(sl, axis=0, keepdims=True)
+    c = jnp.where(valid, sl - s1 / n, 0.0)  # centered about the tile pivot
     c2 = c * c
-    stats = [s1, jnp.sum(c2, axis=0)]
+    stats = [s1, jnp.sum(c2, axis=0, keepdims=True)]
     if order == 4:
-        stats += [jnp.sum(c2 * c, axis=0), jnp.sum(c2 * c2, axis=0)]
-    o_ref[...] = jnp.stack(stats)[None]
+        stats += [jnp.sum(c2 * c, axis=0, keepdims=True),
+                  jnp.sum(c2 * c2, axis=0, keepdims=True)]
+    for k, s in enumerate(stats):
+        o_ref[0, 0, pl.ds(k, 1), :] = s
 
 
-def fused_moment_rows(x2d: jax.Array, valid_rows: int,
+def fused_moment_rows(x: jax.Array, valid_rows: int,
                       tile_rows: Optional[int] = None,
                       interpret: bool = True, order: int = 4) -> jax.Array:
-    """Per-tile sufficient statistics of a canonical (R, C) block.
+    """Per-tile sufficient statistics of a ``(B, R, W)`` row block.
 
-    x2d: (R, C) — R reduction rows × C kept lanes (rows ≥ ``valid_rows``
-    are ignored).  Returns (tiles, order, C) float32: per tile and lane,
+    Rows ``≥ valid_rows`` are ignored.  Returns ``(B, tiles, order, W)``
+    float32: per item, tile and column,
     ``[Σx, Σ(x−x̄_t)², Σ(x−x̄_t)³, Σ(x−x̄_t)⁴][:order]`` with ``x̄_t`` the
-    tile's own valid-row mean (``order=2`` drops the cubic/quartic sums —
-    the variance fast path).  Together with the (static) per-tile valid
-    counts these are exact :class:`~repro.stats.moments.MomentState` tiles,
-    merged by the caller's Chan tree (DESIGN.md §10).  The lane dim is
-    deliberately not tiled — kept axes are operator-sized (channels), not
-    volume-sized.
+    tile column's own valid-row mean (``order=2`` drops the cubic/quartic
+    sums — the variance fast path).  Together with the (static) per-tile
+    valid counts of :func:`moment_tile_counts` these are exact
+    :class:`~repro.stats.moments.MomentState` tiles, merged by the
+    caller's Chan tree (DESIGN.md §10).  ``tile_rows=None`` sizes tiles
+    to :data:`MOMENT_TILE_ELEMS` at the lane-padded width.
     """
     if order not in (2, 4):
         raise ValueError(f"order must be 2 or 4, got {order}")
-    R, C = x2d.shape
-    if tile_rows is None:
-        tile_rows = pick_tile_rows(4, C, order * C, x2d.dtype)
-    tiles = max(1, -(-R // tile_rows))
-    pad_r = tiles * tile_rows - R
-    if pad_r > 0:
-        x2d = jnp.pad(x2d, ((0, pad_r), (0, 0)))
-
-    kernel = functools.partial(_moment_kernel, tile_rows=tile_rows,
+    B, R, W = x.shape
+    T = _moment_tile(R, W, tile_rows)
+    tiles = max(1, -(-R // T))
+    kernel = functools.partial(_moment_kernel, tile_rows=T,
                                valid_rows=int(valid_rows), order=order)
     return pl.pallas_call(
         kernel,
-        grid=(tiles,),
-        in_specs=[pl.BlockSpec(block_shape=None)],     # whole array (HBM ref)
-        out_specs=pl.BlockSpec((1, order, C), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((tiles, order, C), jnp.float32),
+        grid=(B, tiles),
+        # the last tile may overhang R: its rows past valid_rows are masked
+        in_specs=[pl.BlockSpec((1, T, W), lambda b, i: (b, i, 0))],
+        out_specs=pl.BlockSpec((1, 1, order, W),
+                               lambda b, i: (b, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, tiles, order, W), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x2d)
+    )(x)
 
 
-def moment_tile_counts(valid_rows: int, num_rows: int,
-                       tile_rows: Optional[int] = None,
-                       dtype=jnp.float32, lanes: int = 1,
-                       order: int = 4) -> np.ndarray:
+def moment_tile_counts(valid_rows: int, num_rows: int, width: int,
+                       tile_rows: Optional[int] = None) -> np.ndarray:
     """Static per-tile valid-row counts matching :func:`fused_moment_rows`.
 
     Must mirror the kernel's tile sizing exactly — the counts are the
     ``count`` leaves of the per-tile states the caller builds.
     """
-    if tile_rows is None:
-        tile_rows = pick_tile_rows(4, lanes, order * lanes, dtype)
-    tiles = max(1, -(-num_rows // tile_rows))
-    edges = np.arange(tiles, dtype=np.int64) * tile_rows
-    return np.clip(valid_rows - edges, 0, tile_rows).astype(np.float32)
-
-
-def _depthwise_kernel_batched(x_ref, w_ref, o_ref, *,
-                              offsets: Tuple[int, ...], tile_rows: int):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    base = i * tile_rows
-    acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
-    for c, off in enumerate(offsets):
-        sl = pl.load(x_ref, (b, pl.ds(base + off, tile_rows), slice(None)))
-        acc = acc + w_ref[c, :][None, :].astype(jnp.float32) * sl.astype(
-            jnp.float32)
-    o_ref[...] = acc[None].astype(o_ref.dtype)
-
-
-def fused_stencil_rows_depthwise_batched(x_halo: jax.Array,
-                                         weights: jax.Array,
-                                         row_offsets, out_rows: int,
-                                         halo_lo: int,
-                                         tile_rows: Optional[int] = None,
-                                         interpret: bool = True):
-    """Batched per-lane form: (B, rows+halo, K) → (B, out_rows, K)."""
-    B = x_halo.shape[0]
-    R = out_rows
-    numel, K = weights.shape
-    if tile_rows is None:
-        tile_rows = tuned_tile_rows("depthwise", numel, K, K, x_halo.dtype)
-    tiles = -(-R // tile_rows)
-    pad_r = tiles * tile_rows - R
-    if pad_r > 0:
-        x_halo = jnp.pad(x_halo, ((0, 0), (0, pad_r), (0, 0)))
-    W = weights.astype(jnp.float32)
-    offs = tuple(int(o) + halo_lo for o in np.asarray(row_offsets))
-
-    kernel = functools.partial(_depthwise_kernel_batched, offsets=offs,
-                               tile_rows=tile_rows)
-    out = pl.pallas_call(
-        kernel,
-        grid=(B, tiles),
-        in_specs=[
-            pl.BlockSpec(block_shape=None),          # whole array (HBM ref)
-            pl.BlockSpec((numel, K), lambda b, i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_rows, K), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, tiles * tile_rows, K),
-                                       x_halo.dtype),
-        interpret=interpret,
-    )(x_halo, W)
-    return out[:, :R]
+    T = _moment_tile(num_rows, width, tile_rows)
+    tiles = max(1, -(-num_rows // T))
+    edges = np.arange(tiles, dtype=np.int64) * T
+    return np.clip(valid_rows - edges, 0, T).astype(np.float32)

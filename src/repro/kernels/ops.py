@@ -1,19 +1,23 @@
 """jit'd wrappers: rank-agnostic canonicalization → Pallas kernels.
 
 The canonical trick (melt_stencil.py docstring): a stride-1 stencil on
-any rank is computed at EVERY position of the halo-padded flattened
-tensor (output row r ↔ padded flat row r, offsets = QuasiGrid.flat_offsets)
-and the true output region is cropped afterwards ('same' recovers
-in_shape, 'valid' shrinks to out_shape — one rule, `_valid_slices`).
-Extra positions cost (P−N)/N compute (a few %) and buy exact flat-offset
-addressing.
+any rank is computed at EVERY position of the padded flattened volume
+(output position p ↔ padded flat position p, offsets =
+QuasiGrid.flat_offsets) and the true output region is cropped afterwards
+('same' recovers in_shape, 'valid' shrinks to out_shape — one rule,
+`_valid_slices`).  Extra positions cost (P−N)/N compute (a few %) and buy
+exact flat-offset addressing.  Every family runs on ``(B, C, P)`` flat
+volumes — batch items and channels lead, so the kernel's 128-lane rows
+are always dense; bank and depthwise channels move back to channels-last
+only for the public API.
 
-``interpret`` defaults to True off-TPU (this container); on TPU backends
-the same code emits real Pallas kernels.
+``interpret`` defaults to True off-TPU (interpret mode for CPU tests); on
+TPU backends the same code emits compiled Mosaic kernels.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -28,18 +32,6 @@ from repro.kernels import melt_stencil as _ms
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _pad_for(x, grid: QuasiGrid, pad_value, batched: bool = False):
-    pads = ([(0, 0)] if batched else []) + list(zip(grid.pad_lo, grid.pad_hi))
-    return pad_array(x, pads, pad_value)
-
-
-def _halo_extents(grid: QuasiGrid):
-    offs = grid.flat_offsets()
-    halo_lo = int(-offs.min()) if offs.size else 0
-    halo_hi = int(max(0, offs.max())) if offs.size else 0
-    return offs, halo_lo, halo_hi
 
 
 def _valid_slices(grid: QuasiGrid):
@@ -63,39 +55,31 @@ def _check_fused_grid(grid: QuasiGrid):
             "fused path covers stride-1 'same'/'valid' stencils")
 
 
-def _canonical(x, grid: QuasiGrid, pad_value):
-    """(x_flat (P,1), offsets, halo_lo, total_rows, crop_fn)."""
-    xp = _pad_for(x, grid, pad_value)
-    flat = xp.reshape(-1, 1)
-    offs, halo_lo, halo_hi = _halo_extents(grid)
-    # extend with halo rows so every padded position can be computed
-    flat = jnp.pad(flat, ((halo_lo, halo_hi), (0, 0)))
-    pshape = grid.padded_shape
-    slices = _valid_slices(grid)
-
-    def crop(rows):
-        return rows.reshape(pshape)[slices]
-
-    return flat, offs, halo_lo, int(np.prod(pshape)), crop
-
-
-def _canonical_batched(x, grid: QuasiGrid, pad_value):
-    """Batched canonical form: (x_flat (B,P,1), offsets, halo_lo, crop_fn).
-
-    Each item carries its own halo rows, so the offset table never reads
-    across the batch boundary.
-    """
-    xp = _pad_for(x, grid, pad_value, batched=True)
-    flat = xp.reshape(xp.shape[0], -1, 1)
-    offs, halo_lo, halo_hi = _halo_extents(grid)
-    flat = jnp.pad(flat, ((0, 0), (halo_lo, halo_hi), (0, 0)))
-    pshape = grid.padded_shape
-    slices = (slice(None),) + _valid_slices(grid)
-
-    def crop(rows):
-        return rows.reshape((rows.shape[0],) + pshape)[slices]
-
-    return flat, offs, halo_lo, int(np.prod(pshape)), crop
+def _melt(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
+          interpret):
+    """(B, C, *spatial) → (B, C·kper, *out_shape) float32 through the one
+    linear kernel: pad, flatten, every-position pass, crop."""
+    _check_fused_grid(grid)
+    interpret = _interpret_default() if interpret is None else interpret
+    B, C = xc.shape[:2]
+    pads = [(0, 0), (0, 0)] + list(zip(grid.pad_lo, grid.pad_hi))
+    xp = pad_array(xc, pads, pad_value)
+    # extra trailing planes on the first axis make the flat volume fill
+    # whole 128-lane rows, so it reaches the kernel by an N-d pad and a
+    # reshape (padding the flat vector instead lays the whole volume out
+    # one-dimensionally, which XLA compiles slowly); the strides, so the
+    # offsets, do not change, and the extra outputs fall outside the crop
+    lead, rest = grid.padded_shape[0], int(np.prod(grid.padded_shape[1:]))
+    extra = -lead % (_ms.LANES // math.gcd(rest, _ms.LANES))
+    if extra:
+        xp = jnp.pad(xp, [(0, 0), (0, 0), (0, extra)]
+                     + [(0, 0)] * (grid.rank - 1))
+    rows = _ms.fused_melt_rows(xp.reshape(B, C, -1), jnp.asarray(W),
+                               grid.flat_offsets(), tile_rows=tile_rows,
+                               interpret=interpret, family=family)
+    out = rows.reshape((B, rows.shape[1], lead + extra)
+                       + grid.padded_shape[1:])
+    return out[(slice(None), slice(None)) + _valid_slices(grid)]
 
 
 @functools.partial(
@@ -113,83 +97,31 @@ def fused_stencil(x, grid: QuasiGrid, weights, pad_value=0.0,
     (``tuned_tile_rows``, DESIGN.md §16); ``REPRO_TILE_AUTOTUNE=0`` pins
     the ``pick_tile_rows`` VMEM-budget heuristic instead.
     """
-    _check_fused_grid(grid)
-    interpret = _interpret_default() if interpret is None else interpret
-    if batched:
-        flat, offs, halo_lo, total, crop = _canonical_batched(
-            x, grid, pad_value)
-        rows = _ms.fused_stencil_rows_batched(
-            flat, jnp.asarray(weights), offs, total, halo_lo,
-            tile_rows=tile_rows, interpret=interpret)
-        return crop(rows[:, :, 0]).astype(x.dtype)
-    flat, offs, halo_lo, total, crop = _canonical(x, grid, pad_value)
-    rows = _ms.fused_stencil_rows(
-        flat, jnp.asarray(weights), offs, total, halo_lo,
-        tile_rows=tile_rows, interpret=interpret)
-    return crop(rows[:, 0]).astype(x.dtype)
+    xb = x if batched else x[None]
+    out = _melt(xb[:, None], grid, jnp.reshape(weights, (-1, 1)), pad_value,
+                "stencil", tile_rows, interpret)[:, 0]
+    return (out if batched else out[0]).astype(x.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("grid", "pad_value", "interpret", "batched",
-                     "tile_rows", "mxu"))
+                     "tile_rows"))
 def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
-                       interpret=None, batched=False, tile_rows=None,
-                       mxu=None):
+                       interpret=None, batched=False, tile_rows=None):
     """K operators over one melt pass: (..., *spatial) → (..., *spatial, K).
 
-    ``weight_matrix`` is (numel(m), K); each output tile computes the
-    (tile_rows, numel) × (numel, K) melt-tile contraction — one MXU matmul
-    on TPU (``mxu=True``), the same contraction unrolled as outer-product
-    accumulates under interpret mode (``mxu=None`` picks per backend) — so
-    the halo slab load is amortized across all K operators and ``M`` never
-    exists in HBM.  ``tile_rows=None`` is measured per kernel-shape key
-    (``tuned_tile_rows``, DESIGN.md §16).
+    ``weight_matrix`` is (numel(m), K); each grid step reads the input
+    windows of one output tile once and accumulates all K operators from
+    them, so the window load is amortized across the bank and ``M``
+    never exists in HBM.  ``tile_rows=None`` is measured per kernel-shape
+    key (``tuned_tile_rows``, DESIGN.md §16).
     """
-    _check_fused_grid(grid)
-    interpret = _interpret_default() if interpret is None else interpret
-    W = jnp.asarray(weight_matrix)
-    if batched:
-        flat, offs, halo_lo, total, _ = _canonical_batched(
-            x, grid, pad_value)
-        rows = _ms.fused_stencil_bank_rows_batched(
-            flat, W, offs, total, halo_lo, tile_rows=tile_rows,
-            interpret=interpret, mxu=mxu)  # (B, total, K)
-        return _crop_channels(rows, grid, batched=True).astype(x.dtype)
-    flat, offs, halo_lo, total, _ = _canonical(x, grid, pad_value)
-    rows = _ms.fused_stencil_bank_rows(
-        flat, W, offs, total, halo_lo, tile_rows=tile_rows,
-        interpret=interpret, mxu=mxu)  # (total, K)
-    return _crop_channels(rows, grid, batched=False).astype(x.dtype)
-
-
-def _crop_channels(rows, grid: QuasiGrid, batched: bool):
-    """(…, total_padded_rows, K) → (…, *out_shape, K) valid-region crop."""
-    K = rows.shape[-1]
-    lead = rows.shape[:-2]
-    out = rows.reshape(lead + grid.padded_shape + (K,))
-    slices = tuple(slice(None) for _ in lead) + _valid_slices(grid)
-    return out[slices]
-
-
-def _canonical_channels(xc, grid: QuasiGrid, pad_value, batched: bool):
-    """Channel-in-lanes canonical form for depthwise (per-lane) passes.
-
-    xc: (..., *spatial, K).  Spatial dims are halo-padded (the K axis gets
-    zero-width pads, legal under every ``jnp.pad`` mode), then flattened to
-    (…, P, K) rows with the same flat-offset addressing as ``_canonical``.
-    """
-    pads = (([(0, 0)] if batched else [])
-            + list(zip(grid.pad_lo, grid.pad_hi)) + [(0, 0)])
-    xp = pad_array(xc, pads, pad_value)
-    K = xp.shape[-1]
-    flat = (xp.reshape(xp.shape[0], -1, K) if batched
-            else xp.reshape(-1, K))
-    offs, halo_lo, halo_hi = _halo_extents(grid)
-    hpad = ([(0, 0)] if batched else []) + [(halo_lo, halo_hi), (0, 0)]
-    flat = jnp.pad(flat, hpad)
-    total = int(np.prod(grid.padded_shape))
-    return flat, offs, halo_lo, total
+    xb = x if batched else x[None]
+    out = _melt(xb[:, None], grid, weight_matrix, pad_value, "bank",
+                tile_rows, interpret)
+    out = jnp.moveaxis(out, 1, -1)
+    return (out if batched else out[0]).astype(x.dtype)
 
 
 @functools.partial(
@@ -202,39 +134,48 @@ def fused_stencil_depthwise(xc, grid: QuasiGrid, weights, pad_value=0.0,
     column k of ``weights`` (numel(m), K) — the separable 1-D pass primitive.
     ``tile_rows=None`` is measured per kernel-shape key (DESIGN.md §16).
     """
-    _check_fused_grid(grid)
-    interpret = _interpret_default() if interpret is None else interpret
-    W = jnp.asarray(weights)
-    flat, offs, halo_lo, total = _canonical_channels(
-        xc, grid, pad_value, batched)
-    if batched:
-        rows = _ms.fused_stencil_rows_depthwise_batched(
-            flat, W, offs, total, halo_lo, tile_rows=tile_rows,
-            interpret=interpret)
-    else:
-        rows = _ms.fused_stencil_rows_depthwise(
-            flat, W, offs, total, halo_lo, tile_rows=tile_rows,
-            interpret=interpret)
-    return _crop_channels(rows, grid, batched=batched).astype(xc.dtype)
+    xb = xc if batched else xc[None]
+    out = _melt(jnp.moveaxis(xb, -1, 1), grid, weights, pad_value,
+                "depthwise", tile_rows, interpret)
+    out = jnp.moveaxis(out, 1, -1)
+    return (out if batched else out[0]).astype(xc.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("grids", "pad_value", "interpret", "batched"))
+def fused_separable_bank(x, grids, factors, pad_value=0.0, interpret=None,
+                         batched=False):
+    """A factored bank as successive 1-D passes: a bank pass over dim 0,
+    then a depthwise pass per further dim, ``grids[i]``/``factors[i]``
+    each.  The K channels stay leading between passes — the kernels'
+    own layout — and move to channels-last once, at the end."""
+    xb = x if batched else x[None]
+    h = _melt(xb[:, None], grids[0], factors[0], pad_value, "bank", None,
+              interpret)
+    for g, f in zip(grids[1:], factors[1:]):
+        h = _melt(h, g, f, pad_value, "depthwise", None, interpret)
+    out = jnp.moveaxis(h, 1, -1)
+    return (out if batched else out[0]).astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_rows",
                                              "order"))
-def fused_moment_sums(x2d, interpret=None, tile_rows=None, order=4):
-    """Tile-reduction sufficient statistics of a canonical (R, C) block.
+def fused_moment_sums(x3, interpret=None, tile_rows=None, order=4):
+    """Tile-reduction sufficient statistics of a (B, R, W) row block.
 
-    Returns ``(sums, counts)``: ``sums`` is (tiles, order, C) float32
-    per-tile ``[Σx, Σ(x−x̄_t)², Σ(x−x̄_t)³, Σ(x−x̄_t)⁴][:order]`` per lane
+    Returns ``(sums, counts)``: ``sums`` is (B, tiles, order, W) float32
+    per-tile, per-column ``[Σx, Σ(x−x̄_t)², Σ(x−x̄_t)³, Σ(x−x̄_t)⁴][:order]``
     from the Pallas kernel (one pass over the input, no melt matrix in HBM
     — DESIGN.md §10) and ``counts`` the matching (tiles,) static valid-row
-    counts.  ``order=2`` is the variance fast path.
+    counts (every column of a tile holds the same count).  ``order=2`` is
+    the variance fast path.
     """
     interpret = _interpret_default() if interpret is None else interpret
-    R, C = x2d.shape
-    sums = _ms.fused_moment_rows(x2d, R, tile_rows=tile_rows,
+    R, W = x3.shape[1:]
+    sums = _ms.fused_moment_rows(x3, R, tile_rows=tile_rows,
                                  interpret=interpret, order=order)
-    counts = jnp.asarray(_ms.moment_tile_counts(
-        R, R, tile_rows=tile_rows, dtype=x2d.dtype, lanes=C, order=order))
+    counts = jnp.asarray(_ms.moment_tile_counts(R, R, W,
+                                                tile_rows=tile_rows))
     return sums, counts
 
 
@@ -254,12 +195,17 @@ def fused_bilateral(x, op_shape, sigma_d, sigma_r="adaptive",
     log_sp = _spatial_log_weights(grid, sigma_d)
     center = int(np.ravel_multi_index(
         tuple((k - 1) // 2 for k in grid.op_shape), grid.op_shape))
-    flat, offs, halo_lo, total, crop = _canonical(
-        x.astype(jnp.float32), grid, pad_value)
+    offs = grid.flat_offsets()
+    halo_lo, halo_hi = int(max(0, -offs.min())), int(max(0, offs.max()))
+    xp = pad_array(x.astype(jnp.float32),
+                   list(zip(grid.pad_lo, grid.pad_hi)), pad_value)
+    flat = jnp.pad(xp.reshape(-1, 1), ((halo_lo, halo_hi), (0, 0)))
+    total = int(np.prod(grid.padded_shape))
     rows = _bil.bilateral_rows(
         flat, log_sp, offs, total, halo_lo, center, sigma_r=sigma_r,
         interpret=interpret)
-    return crop(rows[:, 0]).astype(x.dtype)
+    return rows[:, 0].reshape(grid.padded_shape)[
+        _valid_slices(grid)].astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "tile", "interpret"))
@@ -288,9 +234,8 @@ def depthwise_conv1d(x, w, interpret=None):
 
 @functools.partial(jax.jit, static_argnames=("L", "interpret"))
 def _dw(xp, w, L, interpret):
-    import functools as ft
-
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, LP, C = xp.shape
     K = w.shape[0]
@@ -299,16 +244,17 @@ def _dw(xp, w, L, interpret):
         b = pl.program_id(0)
         acc = jnp.zeros((L, C), jnp.float32)
         for k in range(K):
-            sl = pl.load(x_ref, (b, pl.ds(k, L), slice(None)))
+            sl = x_ref[b, pl.ds(k, L), :]
             acc = acc + sl.astype(jnp.float32) * w_ref[k, :][None, :].astype(jnp.float32)
-        pl.store(o_ref, (b, slice(None), slice(None)), acc.astype(o_ref.dtype))
+        o_ref[b] = acc.astype(o_ref.dtype)
 
+    # whole arrays in VMEM: this helper serves small sequence models only
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         kernel,
         grid=(B,),
-        in_specs=[pl.BlockSpec(block_shape=None),
-                  pl.BlockSpec(block_shape=None)],
-        out_specs=pl.BlockSpec(block_shape=None),
+        in_specs=[vmem, vmem],
+        out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((B, L, C), xp.dtype),
         interpret=interpret,
     )(xp, w)

@@ -55,6 +55,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.core.grid import (
     compose_footprints,
@@ -643,15 +644,35 @@ class TiledProgram:
             out_itemsize=out_itemsize))
 
     # -- execution ---------------------------------------------------------
-    def _plan_for(self, spec: TileSpec, stack: int = 0) -> TilePlan:
+    def _plan_for(self, spec: TileSpec, stack: int = 0, mesh=None,
+                  axis_name: Optional[str] = None) -> TilePlan:
+        """The class plan for ``spec``; ``stack`` > 0 runs a stack of
+        tiles, split over ``mesh``'s ``axis_name`` when a mesh is given
+        (one ``shard_map`` shard per device: a compiled Pallas kernel
+        cannot be partitioned automatically)."""
         P, opts, program = self.graph, self.opts, self.program
         batched = P.batched or stack > 0
         dt = jnp.dtype(P.x.dtype).name
         ckey = spec.class_key()
         key = (P.signature(), opts.key(), P.batched, dt,
-               tuple(P.x.shape), ckey, stack)
+               tuple(P.x.shape), ckey, stack, mesh, axis_name)
         lead = ((stack,) if stack else
                 ((P.x.shape[0],) if P.batched else ()))
+
+        def run_fn(t):
+            return _run_tile(t, program, spec, opts, batched)
+
+        if mesh is not None:
+            if program.out_kind in ("hist", "cov"):
+                # these states fold their stack inside the shard: each
+                # shard's state gains a leading axis, merged on the host
+                def run_fn(t, one=run_fn):
+                    return jax.tree.map(lambda leaf: leaf[None], one(t))
+
+            run_fn = jax.shard_map(run_fn, mesh=mesh,
+                                   in_specs=PartitionSpec(axis_name),
+                                   out_specs=PartitionSpec(axis_name),
+                                   check_vma=False)
 
         def build():
             if program.out_kind == "array":
@@ -664,8 +685,7 @@ class TiledProgram:
             return TilePlan(
                 ("tiled",) + key, lead + spec.patch_shape, dt, opts,
                 program.steps, program.passes, program.melt_calls,
-                lambda t: _run_tile(t, program, spec, opts, batched),
-                spec=ckey, tile_batch=stack, out_shape=t_out,
+                run_fn, spec=ckey, tile_batch=stack, out_shape=t_out,
                 out_dtype=t_dt)
 
         return get_tile_plan(key, build)
@@ -1110,25 +1130,37 @@ class TiledProgram:
                         stacked[j] = self._read_patch(s)
                 with _span("group/h2d", group=seq[0], size=ways):
                     dev = put_tile_batch(stacked, mesh, axis_name)
-                plan = self._plan_for(group[0], stack=ways)
+                plan = self._plan_for(group[0], stack=ways, mesh=mesh,
+                                      axis_name=axis_name)
                 with _span("group/execute", group=seq[0], size=ways):
                     tile = observe(plan(dev), lambda p=plan, d=dev: p(d))
                 if reduce_out:
                     if self.program.out_kind == "moments":
                         push(merge_along_axis(tile, axis=0))
-                    else:  # hist/cov states already fold the stack axis
-                        push(tile)
+                    else:  # one folded hist/cov state per shard
+                        for j in range(ways):
+                            push(jax.tree.map(lambda leaf, j=j: leaf[j],
+                                              tile))
                 else:
                     sink.stage(tuple(group), tile)
             leftovers.extend(members[n_full:])
-        for spec in leftovers:
+        # ragged remainders run one tile per device, round-robin over the
+        # mesh (not all on the default device); their small fold states
+        # come back to the host together, since committed arrays on
+        # different devices cannot meet in one merge
+        devices = mesh.devices.flat
+        tails = []
+        for k, spec in enumerate(leftovers):
             plan = self._plan_for(spec)
-            dev = jax.device_put(self._read_patch(spec))
+            dev = jax.device_put(self._read_patch(spec),
+                                 devices[k % mesh.devices.size])
             tile = observe(plan(dev), lambda p=plan, d=dev: p(d))
             if reduce_out:
-                push(tile)
+                tails.append(tile)
             else:
                 sink.stage(spec, tile)
+        for tile in jax.device_get(tails):
+            push(tile)
         if live:
             self.liveness_stats.clear()
             self.liveness_stats.update(

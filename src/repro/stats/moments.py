@@ -17,9 +17,9 @@ Three execution paths implement identical math (the engine convention):
 - ``lax``         — the same chunked-centered single-traversal scheme in
   pure XLA (per-chunk states + Chan tree); the fast CPU path.
 - ``fused``       — the Pallas tile-reduction kernel
-  (``repro.kernels.melt_stencil.fused_moment_rows``): one pass over the
-  canonical (rows × lanes) layout, per-tile centered sums in VMEM, Chan
-  tree-merge across tiles — ``M`` never exists in HBM, asserted via
+  (``repro.kernels.melt_stencil.fused_moment_rows``): one pass over
+  row tiles, per-tile centered sums in VMEM, Chan tree-merge across
+  tiles and columns — ``M`` never exists in HBM, asserted via
   ``melt.melt_call_count``.
 
 Concrete calls dispatch through the shared plan cache
@@ -195,14 +195,6 @@ def _split_axes(ndim: int, axes: Tuple[int, ...]):
     return axes, kept
 
 
-def _canonical_2d(x, axes, kept):
-    """Transpose reduced axes first, kept last; flatten to (R, C)."""
-    xt = jnp.transpose(x, axes + kept)
-    R = int(np.prod([x.shape[a] for a in axes])) if axes else 1
-    C = int(np.prod([x.shape[k] for k in kept])) if kept else 1
-    return xt.reshape(R, C), R, C
-
-
 def _direct_state(xcr, order: int = 4) -> MomentState:
     """One-shot centered reduction over the LAST axis of (C, R) → (C,).
 
@@ -264,47 +256,53 @@ def _chunked_state_cr(xcr, order: int = 4) -> MomentState:
 
 
 def _states_from_tiles(sums, counts) -> MomentState:
-    """(tiles, order, C) kernel sums + (tiles,) counts → stacked states."""
-    n = counts[:, None]  # broadcast over lanes
+    """(C, tiles, order, W) kernel sums + (tiles,) counts → stacked
+    states with (C, tiles·W) leaves (one per tile column)."""
+    C, tiles, order, width = sums.shape
+    n = jnp.broadcast_to(counts[None, :, None], (C, tiles, width))
     ns = jnp.where(n == 0, 1.0, n)
-    s1, m2 = sums[:, 0], sums[:, 1]
+    s1, m2 = sums[:, :, 0], sums[:, :, 1]
     z = jnp.zeros_like(s1)
-    m3 = sums[:, 2] if sums.shape[1] == 4 else z
-    m4 = sums[:, 3] if sums.shape[1] == 4 else z
-    return MomentState(jnp.broadcast_to(n, s1.shape), s1 / ns, m2, m3, m4)
+    m3 = sums[:, :, 2] if order == 4 else z
+    m4 = sums[:, :, 3] if order == 4 else z
+    st = MomentState(n, s1 / ns, m2, m3, m4)
+    return jax.tree.map(lambda l: l.reshape(C, tiles * width), st)
 
 
-def _fused_state_2d(x2d, order: int = 4) -> MomentState:
-    """Kernel path over a canonical (R, C) block → state (C,)."""
+#: trailing extents the moment kernel reads as whole rows; outside this
+#: range the flat values pack 128 to a row instead
+_ROW_WIDTHS = (32, 8192)
+
+
+def _fused_state(xt, order: int = 4) -> MomentState:
+    """Kernel path over kept-first ``(C, *reduced)`` → state (C,).
+
+    The kernel's rows are the reduced values' own trailing axis,
+    ``(C, M, W)`` — a reshape that keeps the minor axis, so XLA never lays
+    a whole volume out one-dimensionally (slow to compile at CT sizes)
+    — when ``W`` lies in :data:`_ROW_WIDTHS`.  Otherwise each channel's
+    flat values pack 128 to a row, with a direct tail state for the
+    ragged remainder — zero padding is never counted as data.  Per-column
+    tile states merge pairwise across tiles and columns.
+    """
     from repro.kernels import ops as _ops  # lazy: kernels optional
 
-    sums, counts = _ops.fused_moment_sums(x2d, order=order)
-    return merge_along_axis(_states_from_tiles(sums, counts), axis=0)
-
-
-def _fused_global(x, order: int = 4) -> MomentState:
-    """Fully-global fused reduction with lane packing.
-
-    A flat N-vector becomes (N // 128, 128) kernel rows (per-lane states
-    merged pairwise across lanes) plus a direct tail state for the
-    ragged remainder — zero padding is never counted as data.
-    """
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    nrem = n % _LANES
-    bulk = n - nrem
+    C, W = xt.shape[0], xt.shape[-1]
+    if xt.ndim > 2 and _ROW_WIDTHS[0] <= W <= _ROW_WIDTHS[1]:
+        rows, tail = xt.reshape(C, -1, W), None
+    else:
+        flat = xt.reshape(C, -1)
+        bulk = flat.shape[1] - flat.shape[1] % _LANES
+        rows, tail = flat[:, :bulk].reshape(C, -1, _LANES), flat[:, bulk:]
     parts = []
-    if bulk:
-        st = _fused_state_2d(flat[:bulk].reshape(-1, _LANES), order)
-        parts.append(merge_along_axis(
-            jax.tree.map(lambda l: l[:, None], st), axis=0))
-    if nrem:
-        parts.append(merge_along_axis(
-            jax.tree.map(lambda l: l[:, None],
-                         _direct_state(flat[bulk:].reshape(1, -1), order)),
-            axis=0))
+    if rows.size:
+        sums, counts = _ops.fused_moment_sums(rows, order=order)
+        parts.append(merge_along_axis(_states_from_tiles(sums, counts),
+                                      axis=1))
+    if tail is not None and tail.shape[1]:
+        parts.append(_direct_state(tail, order))
     if not parts:  # zero-element input: the merge identity
-        return MomentState.zero((1,))
+        return MomentState.zero((C,))
     state = parts[0]
     for p in parts[1:]:
         state = merge_moments(state, p)
@@ -382,10 +380,13 @@ def execute_moments(x, axes: Tuple[int, ...], method: str,
             state = jax.tree.map(lambda l: jnp.squeeze(l, axis=0), st)
     elif method == "fused":
         if kept:
-            x2d, R, C = _canonical_2d(x, axes, kept)
-            state = _fused_state_2d(x2d, order)
+            C = int(np.prod(kept_shape))
+            xt = jnp.transpose(x, kept + axes)
+            state = _fused_state(xt.reshape((C,) + xt.shape[len(kept):]),
+                                 order)
         else:
-            state = _fused_global(x, order)
+            st = _fused_state(x[None], order)
+            state = jax.tree.map(lambda l: jnp.squeeze(l, axis=0), st)
     else:
         raise ValueError(f"unknown method {method!r}")
     if order == 2:

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _env import requires_modern_jax_numerics
 from repro.models.attention import banded_attention, chunked_attention
 from repro.kernels.ref import local_attention_ref
 
@@ -113,7 +112,6 @@ def test_windowed_ring_cache_decode():
     assert err < 0.05 * max(scale, 1.0) + 1e-3, (err, scale)
 
 
-@requires_modern_jax_numerics
 def test_mla_absorbed_decode_matches_prefill():
     from repro.configs import get_smoke_config
     from repro.models import build_model

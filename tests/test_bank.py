@@ -351,10 +351,14 @@ def test_pick_tile_rows_aligned_and_bounded():
         sub = 16 if jnp.dtype(dtype).itemsize == 2 else 8
         assert t % sub == 0
         assert sub <= t <= 1024
-    # a tiny budget shrinks the tile; a huge operator can't overflow it
+    # a tiny budget shrinks the tile; a geometry of many input windows
+    # (or wide window spans) can't overflow it
     small = pick_tile_rows(27, 1, 12, jnp.float32, vmem_budget=64 * 1024)
     assert small < pick_tile_rows(27, 1, 12, jnp.float32)
-    assert pick_tile_rows(100_000, 1, 1, jnp.float32) == 8
+    assert pick_tile_rows(27, 1, 1, jnp.float32, windows=100_000) == 8
+    assert pick_tile_rows(27, 1, 1, jnp.float32, span_rows=100_000) == 8
+    assert (pick_tile_rows(27, 1, 1, jnp.float32, windows=27)
+            < pick_tile_rows(27, 1, 1, jnp.float32, windows=3))
 
 
 def test_tile_rows_override_changes_nothing_numerically():
@@ -378,7 +382,9 @@ def test_tile_rows_override_changes_nothing_numerically():
 
 
 def test_bank_mxu_formulations_agree():
-    """The MXU melt-tile matmul and the unrolled accumulate are one math."""
+    """One window pass feeding K operators is the same math as K
+    single-operator passes (the stencil family) and as K per-channel
+    passes over a broadcast input (the depthwise family)."""
     from repro.core.grid import make_quasi_grid
     from repro.kernels import ops
 
@@ -386,7 +392,12 @@ def test_bank_mxu_formulations_agree():
     x = jnp.asarray(rng.randn(13, 12).astype(np.float32))
     grid = make_quasi_grid(x.shape, (3, 3), 1, "same", 1)
     W = jnp.asarray(rng.randn(9, 5), jnp.float32)
-    a = ops.fused_stencil_bank(x, grid, W, mxu=True)
-    b = ops.fused_stencil_bank(x, grid, W, mxu=False)
+    a = ops.fused_stencil_bank(x, grid, W)
+    b = jnp.stack([ops.fused_stencil(x, grid, W[:, k]) for k in range(5)],
+                  axis=-1)
+    c = ops.fused_stencil_depthwise(
+        jnp.broadcast_to(x[..., None], x.shape + (5,)), grid, W)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                rtol=1e-5, atol=1e-6)

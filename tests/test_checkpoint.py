@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _env import requires_axis_type
 
 from repro.checkpoint import checkpoint as ckpt
 from repro.runtime.fault_tolerance import Heartbeat, StragglerMonitor, run_restartable
@@ -111,7 +110,6 @@ def test_heartbeat_staleness(tmp_path):
     assert hb.stale_hosts(3, timeout_s=60) == [2]  # host 2 never beat
 
 
-@requires_axis_type
 def test_elastic_restore_across_meshes(tmp_path):
     """Save on 4 devices, restore on 2 and on 8 — training-equivalent."""
     from conftest import run_with_devices

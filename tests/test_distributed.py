@@ -3,10 +3,8 @@ import numpy as np
 import pytest
 
 from conftest import run_with_devices
-from _env import requires_axis_type
 
 
-@requires_axis_type
 def test_distributed_stencil_matches_single():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
@@ -28,7 +26,6 @@ print("dist-stencil OK")
     assert "dist-stencil OK" in out
 
 
-@requires_axis_type
 def test_distributed_train_step_matches_single_device():
     """The FULL train step (loss+grads+AdamW) on a 2×2 mesh must equal the
     unsharded single-device step — the end-to-end SPMD correctness gate."""
@@ -76,7 +73,6 @@ print("dist-train OK", float(m1["loss"]))
     assert "dist-train OK" in out
 
 
-@requires_axis_type
 def test_serve_step_runs_sharded():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
@@ -104,7 +100,6 @@ print("serve OK")
     assert "serve OK" in out
 
 
-@requires_axis_type
 def test_axis_rules_fallbacks():
     """Rules planner: DP-folding for ≤40B when batch divides; TP when heads
     divide and DP-folding is unavailable; SP fallback; EP vs expert-TP."""

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _env import OLD_JAX_NUMERICS
 from repro.configs import get_config, get_smoke_config, list_archs
 from repro.models import build_model
 
@@ -68,9 +67,6 @@ def test_smoke_train_step_updates(arch):
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_decode_matches_prefill(arch):
-    if arch == "internvl2_2b" and OLD_JAX_NUMERICS:
-        pytest.skip("internvl2_2b decode diverges numerically under "
-                    "the jax 0.4.x pin (environmental; CHANGES.md)")
     cfg = get_smoke_config(arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _env import requires_modern_jax_numerics
 from repro.configs import get_smoke_config
 from repro.models import moe as moe_mod
 
@@ -98,7 +97,6 @@ def test_capacity_bound_respected():
     assert (kept <= C).all()
 
 
-@requires_modern_jax_numerics
 def test_aux_loss_orders_balance():
     """Uniform routing yields lower aux loss than collapsed routing."""
     cfg = _cfg(capacity_factor=2.0)

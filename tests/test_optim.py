@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _env import requires_axis_type
 from repro.optim import adamw
 from repro.optim.compression import dequantize_int8, quantize_int8
 from repro.optim.schedule import warmup_cosine
@@ -66,7 +65,6 @@ def test_int8_quant_roundtrip_bound():
     assert float(err.max()) <= float(s) * 0.5 + 1e-7
 
 
-@requires_axis_type
 def test_compressed_psum_error_feedback_converges():
     """Mean of per-shard gradients via int8 EF-psum drives SGD to the same
     optimum as exact averaging (4 fake devices, shard_map)."""
@@ -75,7 +73,6 @@ def test_compressed_psum_error_feedback_converges():
 
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.optim.compression import compressed_psum, init_error_state
 
@@ -96,9 +93,9 @@ def step(w, err, key):
         g = local_grad(w, x[0])
         gm, e2 = compressed_psum(g, e[0], "d")
         return gm, e2[None]
-    f = shard_map(shard_fn, mesh=mesh,
+    f = jax.shard_map(shard_fn, mesh=mesh,
                   in_specs=(P(), P("d", None), P("d", None)),
-                  out_specs=(P(), P("d", None)), check_rep=False)
+                  out_specs=(P(), P("d", None)), check_vma=False)
     g, err = f(w, xs, err)
     return w - 0.05 * g, err
 

@@ -1,9 +1,7 @@
 """Pipeline parallelism: GPipe schedule == sequential stage application."""
 from conftest import run_with_devices
-from _env import requires_axis_type
 
 
-@requires_axis_type
 def test_pipeline_matches_sequential():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np

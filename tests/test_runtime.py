@@ -9,9 +9,8 @@ turns the checkpoint contract (unsharded leaves + shardings derived from
   real mesh (shapes tree × param-axes tree, every leaf covered);
 - ``restore_elastic`` round-trips values and re-places them on the new
   mesh, including device counts the checkpoint never saw (subprocess with
-  fake host devices; plain ``Mesh`` — no AxisType needed, so this runs
-  under the jax-0.4.37 pin, with the explicit-axis-type variant guarded
-  by ``tests/_env.py``);
+  fake host devices), with both a plain ``Mesh`` and the explicit
+  axis-type ``make_mesh`` spelling;
 - fault-tolerance corners the checkpoint suite leaves open: corrupt
   heartbeat files, heartbeat refresh, straggler warmup/median,
   KeyboardInterrupt passing straight through the crash-only driver, and
@@ -27,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
-from _env import requires_axis_type
 from conftest import run_with_devices
 
 from repro.checkpoint import checkpoint as ckpt
@@ -93,7 +91,7 @@ def test_restore_elastic_missing_step_raises(smoke_model, tmp_path):
 def test_restore_elastic_across_device_counts(tmp_path):
     """Save on a (2, 1) mesh, restore_elastic on (4, 1) and (1, 1) —
     values identical, placement follows the new mesh.  Plain ``Mesh``
-    construction: runs under jax 0.4.37 (no AxisType)."""
+    construction."""
     code = f"""
 import jax, numpy as np
 from jax.sharding import Mesh
@@ -132,10 +130,8 @@ print("elastic re-mesh OK")
     assert "elastic re-mesh OK" in out
 
 
-@requires_axis_type
 def test_restore_elastic_explicit_axis_type_mesh(tmp_path):
-    """The jax>=0.5 spelling (make_mesh + AxisType) of the same contract —
-    guarded: the 0.4.37 pin lacks jax.sharding.AxisType."""
+    """The ``make_mesh`` + ``AxisType`` spelling of the same contract."""
     code = f"""
 import jax, numpy as np
 from repro.checkpoint import checkpoint as ckpt

@@ -1,0 +1,110 @@
+"""Compile the main-path Pallas kernels for a TPU v5e at CT-study size.
+
+Interpret mode cannot see what the chip's compiler refuses: a whole-array
+input staged in VMEM, a slice not aligned to the (8, 128) tiling, or a
+one-lane operand that XLA relays out to 128 lanes on the way in and out.
+Each test here compiles one kernel family that ``method="auto"`` reaches
+on the chip — stencil, bank (K=12), depthwise (K=3), moment — for a
+described (not attached) v5e at the chip smoke's 256×512×512 float32
+volume, and checks that the kernel is really there (``tpu_custom_call``)
+and that the program's memory stays a small multiple of its own input
+and output bytes.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and the suite runs under
+several workers that all import this file.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.filters import curvature_bank
+from repro.core.grid import make_quasi_grid
+from repro.kernels import ops
+
+#: the chip smoke's CT study: 256 slices of 512×512
+CT = (256, 512, 512)
+TILE_ROWS = 256
+#: argument + output + temp bytes over the call's own input + output
+#: bytes; a one-lane operand relaid out to 128 lanes would be ~128
+MEM_BOUND = 4.0
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache, so keep it out of any cache the env turned on
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def _check(lowered, in_shape, out_shape):
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+    own = 4 * (int(np.prod(in_shape)) + int(np.prod(out_shape)))
+    assert used <= MEM_BOUND * own, (used / own, m)
+
+
+def test_stencil_compiles_at_ct_size(one_chip):
+    grid = make_quasi_grid(CT, (3, 3, 3), 1, "same", 1)
+    lowered = ops.fused_stencil.lower(
+        _sds(CT, one_chip), grid=grid, weights=_sds((27,), one_chip),
+        pad_value="edge", interpret=False, tile_rows=TILE_ROWS)
+    _check(lowered, CT, CT)
+
+
+def test_bank_k12_compiles_at_ct_size(one_chip):
+    grid = make_quasi_grid(CT, (3, 3, 3), 1, "same", 1)
+    K = curvature_bank(3).shape[1]
+    assert K == 12
+    lowered = ops.fused_stencil_bank.lower(
+        _sds(CT, one_chip), grid=grid, weight_matrix=_sds((27, K), one_chip),
+        pad_value="edge", interpret=False, tile_rows=TILE_ROWS)
+    _check(lowered, CT, CT + (K,))
+
+
+def test_depthwise_k3_compiles_at_ct_size(one_chip):
+    # the second 1-D pass of the composed gaussian∘gradient bank
+    grid = make_quasi_grid(CT, (1, 7, 1), 1, "valid", 1)
+    lowered = ops.fused_stencil_depthwise.lower(
+        _sds(CT + (3,), one_chip), grid=grid,
+        weights=_sds((7, 3), one_chip), pad_value=0.0, interpret=False,
+        tile_rows=TILE_ROWS)
+    _check(lowered, CT + (3,), grid.out_shape + (3,))
+
+
+def test_moment_compiles_at_ct_size(one_chip):
+    # per-channel variance of a 3-channel gradient field, rows of 512
+    shape = (3, CT[0] * CT[1], CT[2])
+    lowered = ops.fused_moment_sums.lower(
+        _sds(shape, one_chip), interpret=False, order=2)
+    _check(lowered, shape, (3,))

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _env import requires_axis_type
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeSpec
 from repro.launch.mesh import make_host_mesh
@@ -13,7 +12,6 @@ from repro.models import build_model
 from repro.optim import adamw
 
 
-@requires_axis_type
 @pytest.mark.parametrize("arch", ["minitron_4b", "mamba2_370m"])
 def test_loss_decreases(arch):
     """Overfit-one-batch: the canonical learning-dynamics sanity check."""
@@ -39,7 +37,6 @@ def test_loss_decreases(arch):
         losses[:3] + losses[-3:]
 
 
-@requires_axis_type
 def test_microbatched_step_matches_plain():
     import dataclasses
 
