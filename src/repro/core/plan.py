@@ -40,6 +40,10 @@ from repro.core.grid import (
     normalize_tuple,
 )
 from repro.obs.trace import TRACER as _TRACER, span as _span
+from repro.runtime import compile_cache as _compile_cache
+
+# count the compiles of plans' first dispatches (and the tuner's)
+_compile_cache.install()
 
 __all__ = [
     "ExecOptions",
@@ -246,6 +250,14 @@ def _intern(key: tuple, build):
     return plan
 
 
+def _cold_call(plan, *args):
+    """A plan's first dispatch: it pays trace + compile, not just a jit
+    hit, and owns those compiles (``repro.runtime.compile_cache``)."""
+    with _compile_cache.owned(plan.kind), \
+            _span("plan/exec", kind=plan.kind, cold=True):
+        return plan._exec(*args)
+
+
 def plan_cached(key: tuple):
     """The resident plan for ``key`` (or ``None``), without touching LRU
     order or counters — the serving tier's warm/cold probe (a cold key
@@ -328,10 +340,11 @@ class StencilPlan:
     def __call__(self, x: jax.Array, weights: jax.Array) -> jax.Array:
         with self._count_lock:
             self._calls += 1
+        if self._traces == 0:
+            return _cold_call(self, x, weights)
         if not _TRACER.enabled:
             return self._exec(x, weights)
-        # cold == this dispatch pays trace + compile, not just a jit hit
-        with _span("plan/exec", kind=self.kind, cold=self._traces == 0):
+        with _span("plan/exec", kind=self.kind, cold=False):
             return self._exec(x, weights)
 
     def stats(self) -> Dict[str, int]:
@@ -547,9 +560,11 @@ class StatsPlan:
     def __call__(self, x: jax.Array):
         with self._count_lock:
             self._calls += 1
+        if self._traces == 0:
+            return _cold_call(self, x)
         if not _TRACER.enabled:
             return self._exec(x)
-        with _span("plan/exec", kind=self.kind, cold=self._traces == 0):
+        with _span("plan/exec", kind=self.kind, cold=False):
             return self._exec(x)
 
     def stats(self) -> Dict[str, int]:
@@ -646,9 +661,11 @@ class PipePlan:
     def __call__(self, x: jax.Array):
         with self._count_lock:
             self._calls += 1
+        if self._traces == 0:
+            return _cold_call(self, x)
         if not _TRACER.enabled:
             return self._exec(x)
-        with _span("plan/exec", kind=self.kind, cold=self._traces == 0):
+        with _span("plan/exec", kind=self.kind, cold=False):
             return self._exec(x)
 
     def stats(self) -> Dict[str, int]:

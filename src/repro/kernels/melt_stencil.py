@@ -57,6 +57,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime import compile_cache
+
 #: lane width of one TPU vector register; the canonical row width
 LANES = 128
 
@@ -224,15 +226,25 @@ def tuned_tile_rows(family: str, numel: int, c_in: int, c_out: int,
                 box: dict = {}
 
                 def worker():
+                    t0 = time.perf_counter_ns()
                     try:
-                        box["t"] = _measure_candidates(family, numel, c_in,
-                                                       c_out, dtype, cands)
+                        with compile_cache.owned("tune"):
+                            box["t"] = _measure_candidates(
+                                family, numel, c_in, c_out, dtype, cands)
                     except BaseException as e:  # re-raised on the caller
                         box["e"] = e
+                        return
+                    compile_cache.record_tune(
+                        t0, family=family, numel=numel, c_in=c_in,
+                        c_out=c_out,
+                        tile_rows=cands[int(np.argmin(box["t"]))])
 
                 th = threading.Thread(target=worker, name="repro-tile-tune")
-                th.start()
-                th.join()
+                # the caller is usually tracing its executor: the wait is
+                # the tuner's time, not the trace's
+                with compile_cache.waiting():
+                    th.start()
+                    th.join()
                 if "e" in box:
                     raise box["e"]
                 timings = box["t"]

@@ -9,7 +9,8 @@ every engine subsystem:
 - :mod:`repro.obs.metrics` — named counters / gauges / mergeable
   fixed-bucket histograms in one registry;
 - :mod:`repro.obs.export`  — Chrome ``trace_event`` JSON (per-thread
-  tracks; load in ``chrome://tracing`` / Perfetto) + metrics dumps;
+  tracks; load in ``chrome://tracing`` / Perfetto) with the metrics
+  snapshot alongside;
 - :mod:`repro.obs.envhook` — ``REPRO_TRACE=path.json`` captures a trace
   from any run with zero code changes.
 
@@ -24,7 +25,7 @@ from __future__ import annotations
 import contextlib
 
 from repro.obs.envhook import maybe_start as maybe_start_env_trace
-from repro.obs.export import chrome_trace, write_chrome_trace, write_metrics
+from repro.obs.export import chrome_trace, write_chrome_trace
 from repro.obs.metrics import (
     REGISTRY,
     Counter,
@@ -56,7 +57,7 @@ __all__ = [
     "REGISTRY", "MetricsRegistry", "Counter", "Gauge", "Histogram",
     "counter", "gauge", "histogram",
     # export / env hook
-    "chrome_trace", "write_chrome_trace", "write_metrics",
+    "chrome_trace", "write_chrome_trace",
     "maybe_start_env_trace",
     # unified view
     "snapshot",

@@ -1,4 +1,4 @@
-"""Chrome ``trace_event`` JSON export + compact metrics dump (§14).
+"""Chrome ``trace_event`` JSON export (§14).
 
 A :class:`~repro.obs.trace.TraceSnapshot` becomes a JSON file loadable
 in ``chrome://tracing`` / `Perfetto <https://ui.perfetto.dev>`_:
@@ -33,7 +33,6 @@ from repro.obs import trace as _trace
 __all__ = [
     "chrome_trace",
     "write_chrome_trace",
-    "write_metrics",
     "TRACE_EVENT_VERSION",
 ]
 
@@ -99,16 +98,4 @@ def write_chrome_trace(path: str,
     payload = chrome_trace(snap, metrics_snapshot)
     with open(path, "w") as fh:
         json.dump(payload, fh)
-    return str(path)
-
-
-def write_metrics(path: str,
-                  metrics_snapshot: Optional[dict] = None) -> str:
-    """Compact JSON dump of the metrics registry (no timeline)."""
-    if metrics_snapshot is None:
-        metrics_snapshot = _metrics.snapshot()
-    with open(path, "w") as fh:
-        json.dump({"version": TRACE_EVENT_VERSION,
-                   "metrics": metrics_snapshot}, fh, indent=2,
-                  sort_keys=True)
     return str(path)

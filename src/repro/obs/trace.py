@@ -19,6 +19,18 @@ tests/test_obs.py: engine counters are bit-identical with tracing on
 vs off, and the traced tiled stream stays within the benchmark's 5%
 overhead guard even when *on*.
 
+Two more ways in, both for the jax side of the engine, which this
+module never imports:
+
+- :meth:`Tracer.record` adds an interval that has already finished,
+  given its duration (it ends now).  ``repro.runtime.compile_cache``
+  turns JAX's compile-time reports into ``compile/*`` spans this way;
+- :attr:`Tracer.annotate` is a hook: when set, every live span also
+  opens ``annotate(name)`` for its lifetime.  ``compile_cache`` sets it
+  to ``jax.profiler.TraceAnnotation``, so under a profiler session the
+  spans land on the trace's host plane, on the device ops' clock.  With
+  the tracer off the hook is never reached.
+
 ``merged()`` / ``snapshot()`` gather every thread's buffer under the
 registration lock into one immutable :class:`TraceSnapshot` — the input
 to ``repro.obs.export``'s Chrome-trace writer, where each thread
@@ -139,9 +151,11 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    """A live span: clock read on enter, ring append on exit."""
+    """A live span: clock read on enter, ring append on exit (and the
+    tracer's ``annotate`` hook, when set, open in between)."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_buf", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_attrs", "_buf", "_t0", "_depth",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -149,6 +163,10 @@ class _Span:
         self._attrs = attrs
 
     def __enter__(self):
+        hook = self._tracer.annotate
+        self._ann = None if hook is None else hook(self._name)
+        if self._ann is not None:
+            self._ann.__enter__()
         buf = self._tracer._buf()
         self._buf = buf
         self._depth = buf.depth
@@ -162,6 +180,8 @@ class _Span:
         buf.depth -= 1
         buf.push(Event(self._name, self._t0, t1 - self._t0, self._depth,
                        self._attrs))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -172,6 +192,9 @@ class Tracer:
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self.enabled = False
+        #: ``name -> context manager`` opened around every live span
+        #: (None: no hook)
+        self.annotate = None
         self.capacity = capacity
         self.epoch_ns = time.perf_counter_ns()
         self._local = threading.local()
@@ -200,6 +223,18 @@ class Tracer:
         buf = self._buf()
         buf.push(Event(name, time.perf_counter_ns(), None, buf.depth,
                        attrs))
+
+    def record(self, name: str, dur_ns: int, **attrs) -> None:
+        """Record a span that has just finished after ``dur_ns``: it ends
+        now and starts ``dur_ns`` earlier, at the current nesting depth.
+        For intervals timed by someone else and reported after the fact
+        (JAX's compile phases)."""
+        if not self.enabled:
+            return
+        buf = self._buf()
+        t1 = time.perf_counter_ns()
+        dur_ns = max(int(dur_ns), 0)
+        buf.push(Event(name, t1 - dur_ns, dur_ns, buf.depth, attrs))
 
     # -- lifecycle ----------------------------------------------------------
     def enable(self, capacity: Optional[int] = None) -> None:
