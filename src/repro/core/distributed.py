@@ -211,7 +211,7 @@ def sharded_pipe_fn(
     one linear op and composition happens on-device only.  ``zscore`` /
     ``cov`` stages are not yet routed either.
     """
-    from repro.pipe.compile import _apply_reduce
+    from repro.pipe.compile import _apply_pointwise, _apply_reduce
     from repro.pipe.fuse import (
         LinearStep, PointwiseStep, ReduceStep, ZscoreStep, build_program,
     )
@@ -276,7 +276,8 @@ def sharded_pipe_fn(
                 hh, lgrid, jnp.asarray(step.weights[:, 0]), 0.0, meth,
                 batched)
         return engine.execute_stencil_bank(
-            hh, lgrid, jnp.asarray(step.weights), 0.0, meth, batched)
+            hh, lgrid, jnp.asarray(step.weights), 0.0, meth, batched,
+            pointwise=step.pointwise)
 
     out_is_state = program.out_kind != "array"
 
@@ -286,7 +287,7 @@ def sharded_pipe_fn(
             if isinstance(step, LinearStep):
                 h = _local_linear(h, step)
             elif isinstance(step, PointwiseStep):
-                h = step.fn(h)
+                h = _apply_pointwise(h, step, batched, rank)
             elif isinstance(step, ReduceStep):
                 if step.kind == "moments":
                     h = _apply_reduce(h, step, opts, batched,

@@ -61,6 +61,7 @@ __all__ = [
     "apply_stencil_bank",
     "execute_stencil",
     "execute_stencil_bank",
+    "apply_channels_first",
     "execute_separable_bank",
     "separable_factors",
     "MeltEngine",
@@ -137,13 +138,14 @@ def _conv_lhs_pads(x, grid: QuasiGrid, pad_value, lead):
 
 
 def _bank_lax(x, grid: QuasiGrid, W, pad_value, batched,
-              depthwise: bool = False):
+              depthwise: bool = False, channels_last: bool = True):
     """Grouped ``conv_general_dilated`` with K output channels.
 
     Dense bank: input channel 1 fans out to K outputs.  ``depthwise``:
     input channel k maps to output k via ``feature_group_count=K`` (the
     separable per-lane pass); the caller passes ``x`` with a trailing
-    channel axis.
+    channel axis.  ``channels_last=False`` keeps the convolution's own
+    (N, K, *out_shape) layout.
     """
     K = W.shape[1]
     if not depthwise:
@@ -156,11 +158,12 @@ def _bank_lax(x, grid: QuasiGrid, W, pad_value, batched,
             xc = xc[None]
         xp, pad_cfg = _conv_lhs_pads(xc, grid, pad_value, [(0, 0), (0, 0)])
         lhs = xp  # (N, K, *spatial)
-    kern = W.T.reshape((K, 1) + grid.op_shape).astype(x.dtype)  # (O, I, ...)
+    # (..., I, O): the bank's own row order, so no transpose of W
+    kern = W.reshape(grid.op_shape + (1, K)).astype(x.dtype)
     spatial = "".join(chr(ord("0") + i) for i in range(grid.rank))
     dn = jax.lax.conv_dimension_numbers(
         lhs.shape, kern.shape,
-        ("NC" + spatial, "OI" + spatial, "NC" + spatial),
+        ("NC" + spatial, spatial + "IO", "NC" + spatial),
     )
     out = jax.lax.conv_general_dilated(
         lhs, kern,
@@ -170,26 +173,63 @@ def _bank_lax(x, grid: QuasiGrid, W, pad_value, batched,
         dimension_numbers=dn,
         feature_group_count=K if depthwise else 1,
     )  # (N, K, *out_shape)
-    out = jnp.moveaxis(out, 1, -1)  # channels last
+    if channels_last:
+        out = jnp.moveaxis(out, 1, -1)
     return out if batched else out[0]
 
 
+def _trailing_channels(out, rank: int, batched: bool):
+    """A channel axis on the leading non-batch axis moves to trailing."""
+    lead = 1 if batched else 0
+    return jnp.moveaxis(out, lead, -1) if out.ndim > lead + rank else out
+
+
+def apply_channels_first(fn, h, rank: int, batched: bool,
+                         channel_major: bool = False):
+    """Run ``fn``, elementwise over channel-major values, on ``h``.
+
+    A trailing channel axis of ``h`` moves to the leading non-batch axis
+    first, unless ``channel_major`` says it is there already; a channel
+    axis in the result moves back to trailing.  ``rank`` is the spatial
+    rank (DESIGN.md §11)."""
+    lead = 1 if batched else 0
+    if h.ndim > lead + rank and not channel_major:
+        h = jnp.moveaxis(h, -1, lead)
+    return _trailing_channels(fn(h), rank, batched)
+
+
 def execute_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value,
-                         method: str, batched: bool = False):
-    """K operators, one melt pass: (..., *spatial) → (..., *out_shape, K)."""
+                         method: str, batched: bool = False,
+                         pointwise=None):
+    """K operators, one melt pass: (..., *spatial) → (..., *out_shape, K).
+
+    ``pointwise`` is an elementwise function of channel-major values
+    (``apply_channels_first``) run on the bank's output where it is
+    computed: the lax and fused paths compute channel-major, and the
+    fused path hands it the kernel's rows before their crop, so the
+    K-channel field is never relaid out (DESIGN.md §11).
+    """
     W = jnp.asarray(weight_matrix)
-    if method == "materialize":
-        return _bank_materialize(x, grid, W, pad_value, batched)
-    if method == "lax":
-        return _bank_lax(x, grid, W, pad_value, batched)
     if method == "fused":
         from repro.kernels import melt_stencil_ops  # lazy: kernels optional
 
-        return melt_stencil_ops.fused_stencil_bank(
+        out = melt_stencil_ops.fused_stencil_bank(
             x, grid, W, pad_value=normalize_pad_value(pad_value),
-            batched=batched,
+            batched=batched, pointwise=pointwise,
         )
-    raise ValueError(f"unknown method {method!r}")
+        return (out if pointwise is None
+                else _trailing_channels(out, grid.rank, batched))
+    if method == "materialize":
+        out = _bank_materialize(x, grid, W, pad_value, batched)
+    elif method == "lax":
+        out = _bank_lax(x, grid, W, pad_value, batched,
+                        channels_last=pointwise is None)
+    else:
+        raise ValueError(f"unknown method {method!r}")
+    if pointwise is None:
+        return out
+    return apply_channels_first(pointwise, out, grid.rank, batched,
+                                channel_major=method == "lax")
 
 
 def _depthwise_materialize(xc, grid: QuasiGrid, Wd, pad_value, batched):
@@ -227,7 +267,8 @@ def execute_stencil_depthwise(xc, grid: QuasiGrid, weights, pad_value,
 
 
 def execute_separable_bank(x, grid: QuasiGrid, factors, pad_value,
-                           method: str, batched: bool = False):
+                           method: str, batched: bool = False,
+                           pointwise=None):
     """Run a factored bank as ``rank`` successive 1-D passes.
 
     ``factors[i]`` is (op_shape[i], K).  Pass 0 is a dense 1-D bank (one
@@ -239,7 +280,7 @@ def execute_separable_bank(x, grid: QuasiGrid, factors, pad_value,
     read): pass ``i`` decimates only dim ``i`` by the grid's own stride
     there, so ``Σ_a Π_d w_d[a_d] · x[s·g + a]`` factors into the per-dim
     passes and the intermediate shapes walk from ``in_shape`` down to
-    ``out_shape``.
+    ``out_shape``.  ``pointwise`` is as for ``execute_stencil_bank``.
     """
     rank = grid.rank
 
@@ -255,15 +296,18 @@ def execute_separable_bank(x, grid: QuasiGrid, factors, pad_value,
     if method == "fused":
         from repro.kernels import melt_stencil_ops  # lazy: kernels optional
 
-        return melt_stencil_ops.fused_separable_bank(
+        out = melt_stencil_ops.fused_separable_bank(
             x, tuple(grids), tuple(factors),
             pad_value=normalize_pad_value(pad_value), batched=batched)
-    out = execute_stencil_bank(x, grids[0], factors[0], pad_value, method,
-                               batched)
-    for g, f in zip(grids[1:], factors[1:]):
-        out = execute_stencil_depthwise(out, g, f, pad_value, method,
-                                        batched)
-    return out
+    else:
+        out = execute_stencil_bank(x, grids[0], factors[0], pad_value,
+                                   method, batched)
+        for g, f in zip(grids[1:], factors[1:]):
+            out = execute_stencil_depthwise(out, g, f, pad_value, method,
+                                            batched)
+    if pointwise is None:
+        return out
+    return apply_channels_first(pointwise, out, rank, batched)
 
 
 #: memoized factorization results keyed on (weight bytes, dtype, shape, op

@@ -234,13 +234,45 @@ def hessian(x: jax.Array, *, method: str = "auto", pad_value="edge",
     return D.reshape(D.shape[:-1] + (rank, rank))
 
 
-def _curvature_combine(rank: int):
-    """det(H) / (1 + |∇|²)² over the [∇ | vec(H)] channel axis."""
+def _det_planes(H, r: int):
+    """det of the r×r matrix field ``H(i, j)`` (one plane per entry).
 
-    def fn(D):
-        g = D[..., :rank]
-        H = D[..., rank:].reshape(D.shape[:-1] + (rank, rank))
-        return jnp.linalg.det(H) / (1.0 + jnp.sum(g * g, axis=-1)) ** 2
+    Rank ≤ 3 expands the cofactors, term for term as ``jnp.linalg.det``
+    does for 2×2 and 3×3 (all entries, no symmetry assumed); larger
+    ranks stack the planes into (..., r, r) for ``jnp.linalg.det``."""
+    if r == 1:
+        return H(0, 0)
+    if r == 2:
+        return H(0, 0) * H(1, 1) - H(0, 1) * H(1, 0)
+    if r == 3:
+        return (H(0, 0) * H(1, 1) * H(2, 2)
+                + H(0, 1) * H(1, 2) * H(2, 0)
+                + H(0, 2) * H(1, 0) * H(2, 1)
+                - H(0, 2) * H(1, 1) * H(2, 0)
+                - H(0, 0) * H(1, 2) * H(2, 1)
+                - H(0, 1) * H(1, 0) * H(2, 2))
+    rows = [jnp.stack([H(i, j) for j in range(r)], -1) for i in range(r)]
+    return jnp.linalg.det(jnp.stack(rows, -2))
+
+
+def _curvature_combine(rank: int):
+    """det(H) / (1 + |∇|²)² over the channel-major [∇ | vec(H)] planes.
+
+    Takes the bank's channels on the leading non-batch axis, (..., K,
+    *spatial) — a ``channels_first`` pointwise stage — so every operand
+    is a whole plane and the combine is one elementwise pass."""
+
+    def fn(C):
+        cax = C.ndim - rank - 1
+
+        def plane(k):
+            return C[(slice(None),) * cax + (k,)]
+
+        sq = plane(0) * plane(0)
+        for i in range(1, rank):
+            sq = sq + plane(i) * plane(i)
+        det = _det_planes(lambda i, j: plane(rank + rank * i + j), rank)
+        return det / (1.0 + sq) ** 2
 
     return fn
 
@@ -261,5 +293,6 @@ def gaussian_curvature(x: jax.Array, *, pad_value="edge",
     rank = x.ndim - (1 if batched else 0)
     P = (_pipe_for(x.astype(jnp.float32), batched)
          .bank((3,) * rank, curvature_bank(rank))
-         .pointwise(_curvature_combine(rank), key=f"gauss-curv-{rank}"))
+         .pointwise(_curvature_combine(rank), key=f"gauss-curv-{rank}",
+                    channels_first=True))
     return P.run(method=method, pad_value=pad_value, out_dtype=x.dtype)

@@ -55,10 +55,10 @@ def _check_fused_grid(grid: QuasiGrid):
             "fused path covers stride-1 'same'/'valid' stencils")
 
 
-def _melt(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
-          interpret):
-    """(B, C, *spatial) → (B, C·kper, *out_shape) float32 through the one
-    linear kernel: pad, flatten, every-position pass, crop."""
+def _melt_rows(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
+               interpret):
+    """(B, C, *spatial) → (B, C·kper, rows, 128) float32: the linear
+    kernel's own output, every position of the padded flat volume."""
     _check_fused_grid(grid)
     interpret = _interpret_default() if interpret is None else interpret
     B, C = xc.shape[:2]
@@ -69,17 +69,37 @@ def _melt(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
     # reshape (padding the flat vector instead lays the whole volume out
     # one-dimensionally, which XLA compiles slowly); the strides, so the
     # offsets, do not change, and the extra outputs fall outside the crop
-    lead, rest = grid.padded_shape[0], int(np.prod(grid.padded_shape[1:]))
-    extra = -lead % (_ms.LANES // math.gcd(rest, _ms.LANES))
+    extra = _extra_planes(grid)
     if extra:
         xp = jnp.pad(xp, [(0, 0), (0, 0), (0, extra)]
                      + [(0, 0)] * (grid.rank - 1))
     rows = _ms.fused_melt_rows(xp.reshape(B, C, -1), jnp.asarray(W),
                                grid.flat_offsets(), tile_rows=tile_rows,
                                interpret=interpret, family=family)
-    out = rows.reshape((B, rows.shape[1], lead + extra)
+    return rows.reshape(B, rows.shape[1], -1, _ms.LANES)
+
+
+def _extra_planes(grid: QuasiGrid) -> int:
+    lead, rest = grid.padded_shape[0], int(np.prod(grid.padded_shape[1:]))
+    return -lead % (_ms.LANES // math.gcd(rest, _ms.LANES))
+
+
+def _crop_rows(rows, grid: QuasiGrid):
+    """(..., rows, 128) → (..., *out_shape): the padded volume back from
+    its rows, cropped.  On the chip this is a relayout of every channel
+    (the rows' tiling is not the volume's), so it comes last."""
+    lead = rows.shape[:-2]
+    out = rows.reshape(lead + (grid.padded_shape[0] + _extra_planes(grid),)
                        + grid.padded_shape[1:])
-    return out[(slice(None), slice(None)) + _valid_slices(grid)]
+    return out[(slice(None),) * len(lead) + _valid_slices(grid)]
+
+
+def _melt(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
+          interpret):
+    """(B, C, *spatial) → (B, C·kper, *out_shape) float32 through the one
+    linear kernel: pad, flatten, every-position pass, crop."""
+    return _crop_rows(_melt_rows(xc, grid, W, pad_value, family, tile_rows,
+                                 interpret), grid)
 
 
 @functools.partial(
@@ -106,9 +126,10 @@ def fused_stencil(x, grid: QuasiGrid, weights, pad_value=0.0,
 @functools.partial(
     jax.jit,
     static_argnames=("grid", "pad_value", "interpret", "batched",
-                     "tile_rows"))
+                     "tile_rows", "pointwise"))
 def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
-                       interpret=None, batched=False, tile_rows=None):
+                       interpret=None, batched=False, tile_rows=None,
+                       pointwise=None):
     """K operators over one melt pass: (..., *spatial) → (..., *spatial, K).
 
     ``weight_matrix`` is (numel(m), K); each grid step reads the input
@@ -116,12 +137,29 @@ def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
     them, so the window load is amortized across the bank and ``M``
     never exists in HBM.  ``tile_rows=None`` is measured per kernel-shape
     key (``tuned_tile_rows``, DESIGN.md §16).
+
+    ``pointwise`` is an elementwise function of channel-major values,
+    (..., K, *spatial) → (..., [K',] *spatial).  It runs on the kernel's
+    own rows — the spatial axes it sees are the flat padded volume's,
+    ``(1, …, rows, 128)`` — before they are cropped, so the K-channel
+    field is never relaid out; only its result is.  The result is then
+    channel-major: (..., [K',] *out_shape).
     """
     xb = x if batched else x[None]
-    out = _melt(xb[:, None], grid, weight_matrix, pad_value, "bank",
-                tile_rows, interpret)
-    out = jnp.moveaxis(out, 1, -1)
-    return (out if batched else out[0]).astype(x.dtype)
+    if pointwise is None:
+        out = _melt(xb[:, None], grid, weight_matrix, pad_value, "bank",
+                    tile_rows, interpret)
+        out = jnp.moveaxis(out, 1, -1)
+        return (out if batched else out[0]).astype(x.dtype)
+    rows = _melt_rows(xb[:, None], grid, weight_matrix, pad_value, "bank",
+                      tile_rows, interpret).astype(x.dtype)
+    B, K, R = rows.shape[:3]
+    flat = ((R * _ms.LANES,) if grid.rank == 1
+            else (1,) * (grid.rank - 2) + (R, _ms.LANES))
+    res = pointwise(rows.reshape((B, K) + flat) if batched
+                    else rows.reshape((K,) + flat))
+    res = res.reshape(res.shape[:res.ndim - grid.rank] + (R, _ms.LANES))
+    return _crop_rows(res, grid)
 
 
 @functools.partial(

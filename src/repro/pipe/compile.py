@@ -121,7 +121,8 @@ def _apply_linear(h, step: LinearStep, opts: ExecOptions, batched: bool):
     meth = opts.resolved_method
     if step.factors is not None:
         out = engine.execute_separable_bank(
-            h, step.grid, step.factors, opts.pad_value, meth, batched)
+            h, step.grid, step.factors, opts.pad_value, meth, batched,
+            pointwise=step.pointwise)
         return out[..., 0] if step.kind == "stencil" else out
     if step.kind == "stencil":
         return engine.execute_stencil(
@@ -129,7 +130,17 @@ def _apply_linear(h, step: LinearStep, opts: ExecOptions, batched: bool):
             meth, batched)
     return engine.execute_stencil_bank(
         h, step.grid, jnp.asarray(step.weights), opts.pad_value, meth,
-        batched)
+        batched, pointwise=step.pointwise)
+
+
+def _apply_pointwise(h, step, batched: bool, rank: int):
+    """An elementwise stage under its layout contract: a channel axis
+    reaches a ``channels_first`` stage leading (DESIGN.md §11)."""
+    if not step.channels_first:
+        return step.fn(h)
+    from repro.core.engine import apply_channels_first
+
+    return apply_channels_first(step.fn, h, rank, batched)
 
 
 def _apply_zscore(h, step: ZscoreStep, opts: ExecOptions, batched: bool):
@@ -234,7 +245,7 @@ def _run_program(x, program: PipelineProgram, opts: ExecOptions,
         elif isinstance(step, SplitStep):
             h = _apply_split(h, step, opts, batched)
         elif isinstance(step, PointwiseStep):
-            h = step.fn(h)
+            h = _apply_pointwise(h, step, batched, len(program.out_shape))
         elif isinstance(step, ZscoreStep):
             h = _apply_zscore(h, step, opts, batched)
         elif isinstance(step, ReduceStep):
@@ -295,7 +306,7 @@ def run(P: Pipe, method="auto", pad_value="edge", out_dtype=None):
         return x if opts.out_dtype is None else x.astype(opts.out_dtype)
     if all(isinstance(op, PointwiseOp) for op in P.ops):
         for op in P.ops:
-            x = op.fn(x)
+            x = _apply_pointwise(x, op, P.batched, P.rank)
         return x if opts.out_dtype is None else x.astype(opts.out_dtype)
     lowered = _lower_trivial(P, opts)
     if lowered is not None:

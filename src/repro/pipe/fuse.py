@@ -1,6 +1,6 @@
 """The melt-fusing planner: op chain → minimum-pass step program.
 
-Three fusion rules (DESIGN.md §11):
+Four fusion rules (DESIGN.md §11):
 
 1. **Weight composition** — adjacent linear stages merge into ONE
    operator-bank column when the rewrite is *exact*.  Every stage but the
@@ -40,6 +40,14 @@ Three fusion rules (DESIGN.md §11):
    carries its own dim's stride).  Plain ``.stencil``/``.gaussian``
    stages stay dense for parity with ``apply_stencil``.
 
+4. **Channel-major hand-off** — a ``channels_first`` pointwise stage
+   directly after a bank group rides that group's step
+   (``LinearStep.pointwise``): the bank hands it the K channels where
+   it computes them, before any relayout, and only the stage's result
+   is laid out as the value (``pipe/channel_major_handoff`` counts the
+   plans that do).  After any other step the stage runs on its own,
+   with the channels moved to the front for it.
+
 The program records ``passes`` (logical fused traversals; a split counts
 as one) and ``melt_calls`` (the exact ``melt()`` count the materialize
 path pays: separable groups pay one 1-D melt per dim, a split pays its
@@ -51,6 +59,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.grid import (
@@ -60,6 +69,7 @@ from repro.core.grid import (
     make_quasi_grid,
 )
 from repro.core.plan import ExecOptions, separable_profitable
+from repro.obs.metrics import counter as _counter
 from repro.pipe.graph import (
     CovOp,
     HistOp,
@@ -139,6 +149,7 @@ class LinearStep:
     kind: str                      # 'stencil' (squeeze K) | 'bank' (keep K)
     factors: Optional[tuple]       # separable per-dim factors, or None
     fused_from: int                # how many graph ops merged into this pass
+    pointwise: object = None       # a channels-first stage on its output
 
     @property
     def melt_calls(self) -> int:
@@ -148,6 +159,21 @@ class LinearStep:
 @dataclasses.dataclass
 class PointwiseStep:
     fn: object
+    channels_first: bool = False   # fn takes its channels leading
+
+
+def _channels_after(op: PointwiseOp, lead: tuple, spatial: tuple,
+                    channels: int, dtype) -> int:
+    """The channel extent an elementwise stage leaves (0 = none).
+
+    ``fn`` may keep, resize or drop the channel axis it is given; its
+    abstract result says which, so the executors crop and reduce the
+    axes the value really has."""
+    cax = len(lead) if op.channels_first else len(lead) + len(spatial)
+    shape = list(lead + spatial)
+    shape.insert(cax, channels)
+    out = jax.eval_shape(op.fn, jax.ShapeDtypeStruct(tuple(shape), dtype))
+    return out.shape[cax] if len(out.shape) > len(shape) - 1 else 0
 
 
 @dataclasses.dataclass
@@ -215,7 +241,8 @@ class PipelineProgram:
                 tag = "x".join(map(str, s.grid.op_shape))
                 sep = "sep" if s.factors is not None else "dense"
                 names.append(f"linear[{tag},K={s.weights.shape[1]},{sep},"
-                             f"fused={s.fused_from}]")
+                             f"fused={s.fused_from}]"
+                             + ("+pointwise" if s.pointwise else ""))
             elif isinstance(s, SplitStep):
                 tag = "x".join(map(str, s.interior.grid.op_shape))
                 names.append(f"split[{tag},K={s.interior.weights.shape[1]},"
@@ -365,6 +392,7 @@ def build_program(P: Pipe, opts: ExecOptions,
 
     steps = []
     cur_shape = P.spatial_shape
+    lead = tuple(P.x.shape[:1]) if P.batched else ()
     channels = 0
     out_kind = "array"
 
@@ -420,7 +448,19 @@ def build_program(P: Pipe, opts: ExecOptions,
             pending.append(op)
         elif isinstance(op, PointwiseOp):
             flush()
-            steps.append(PointwiseStep(op.fn))
+            last = steps[-1] if steps else None
+            if (op.channels_first and isinstance(last, LinearStep)
+                    and last.kind == "bank" and last.pointwise is None):
+                # rule 4: the bank hands its channel-major output to the
+                # stage where it computes it — no relayout of its K
+                # channels (the lax and fused paths)
+                steps[-1] = dataclasses.replace(last, pointwise=op.fn)
+                _counter("pipe/channel_major_handoff").inc()
+            else:
+                steps.append(PointwiseStep(op.fn, op.channels_first))
+            if channels:
+                channels = _channels_after(op, lead, tuple(cur_shape),
+                                           channels, P.x.dtype)
         elif isinstance(op, ZscoreOp):
             flush()
             grid = make_quasi_grid(cur_shape, op.window, 1, "same", 1)

@@ -105,20 +105,26 @@ class PointwiseOp:
 
     ``key`` names the function for plan interning; anonymous functions key
     on ``id(fn)`` (the plan pins ``fn``, so the id cannot be recycled while
-    the plan lives).
+    the plan lives).  ``channels_first`` is ``fn``'s layout contract: it
+    takes (and returns) a channel axis on the leading non-batch axis
+    rather than the trailing one (DESIGN.md §11); it is part of the
+    signature, since the same ``fn`` under the other contract computes
+    something else.
     """
 
-    __slots__ = ("fn", "key")
+    __slots__ = ("fn", "key", "channels_first")
 
-    def __init__(self, fn, key: Optional[str] = None):
+    def __init__(self, fn, key: Optional[str] = None,
+                 channels_first: bool = False):
         if not callable(fn):
             raise ValueError(f"pointwise op needs a callable, got {fn!r}")
         self.fn = fn
         self.key = key
+        self.channels_first = bool(channels_first)
 
     def signature(self) -> tuple:
         return ("ptw", self.key if self.key is not None
-                else ("id", id(self.fn)))
+                else ("id", id(self.fn)), self.channels_first)
 
 
 class ZscoreOp:
@@ -311,10 +317,19 @@ class Pipe:
             1, padding, 1))
 
     # -- nonlinear / window stages -----------------------------------------
-    def pointwise(self, fn, *, key: Optional[str] = None) -> "Pipe":
+    def pointwise(self, fn, *, key: Optional[str] = None,
+                  channels_first: bool = False) -> "Pipe":
         """Elementwise stage ``fn(value) -> value`` (fused into the
-        surrounding group; never costs a melt pass)."""
-        return self._append(PointwiseOp(fn, key))
+        surrounding group; never costs a melt pass).
+
+        A bank's channel axis reaches ``fn`` trailing, (..., *spatial, K);
+        ``channels_first=True`` declares that ``fn`` takes it leading,
+        (..., K, *spatial), and returns any channel axis it keeps there
+        too.  A bank directly before such a stage hands over its output
+        as it computes it, with no relayout: on the fused path the
+        kernel's flat rows stand in for the spatial axes, so ``fn`` must
+        not depend on their extents (DESIGN.md §11)."""
+        return self._append(PointwiseOp(fn, key, channels_first))
 
     def zscore(self, window, *, weights="box", sigma=None,
                eps: float = 1e-5) -> "Pipe":

@@ -206,13 +206,15 @@ def _tile_linear(h, step: LinearStep, dim_pads, opts: ExecOptions,
     meth = opts.resolved_method
     if step.factors is not None:
         out = engine.execute_separable_bank(h, lgrid, step.factors, 0.0,
-                                            meth, batched)
+                                            meth, batched,
+                                            pointwise=step.pointwise)
         return out[..., 0] if step.kind == "stencil" else out
     if step.kind == "stencil":
         return engine.execute_stencil(
             h, lgrid, jnp.asarray(step.weights[:, 0]), 0.0, meth, batched)
     return engine.execute_stencil_bank(
-        h, lgrid, jnp.asarray(step.weights), 0.0, meth, batched)
+        h, lgrid, jnp.asarray(step.weights), 0.0, meth, batched,
+        pointwise=step.pointwise)
 
 
 def _tile_zscore(h, step: ZscoreStep, dim_pads, opts: ExecOptions,
@@ -246,7 +248,7 @@ def _tile_zscore(h, step: ZscoreStep, dim_pads, opts: ExecOptions,
 
 def _run_tile(patch, program: PipelineProgram, spec: TileSpec,
               opts: ExecOptions, batched: bool):
-    from repro.pipe.compile import _apply_reduce
+    from repro.pipe.compile import _apply_pointwise, _apply_reduce
 
     h = patch
     li = 0
@@ -258,7 +260,7 @@ def _run_tile(patch, program: PipelineProgram, spec: TileSpec,
             h = _tile_zscore(h, step, spec.stage_pads[li], opts, batched)
             li += 1
         elif isinstance(step, PointwiseStep):
-            h = step.fn(h)
+            h = _apply_pointwise(h, step, batched, len(spec.crop))
         elif isinstance(step, ReduceStep):
             # crop BEFORE reducing: the reduction must see exactly the
             # tile's own output box, never halo leftovers

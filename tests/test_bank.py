@@ -28,6 +28,7 @@ from repro.core import (
     separable_factors,
 )
 from repro.core.plan import separable_eligible, separable_profitable
+from repro.pipe import pipe
 
 BATCH = 3
 METHODS = ("materialize", "lax", "fused")
@@ -246,20 +247,47 @@ def test_gradient_hessian_exact_on_quadratics():
             rtol=1e-4, atol=1e-4)
 
 
-def test_curvature_methods_agree_batched_and_not():
+#: one volume per rank for the curvature combine (rank 4 runs the bank
+#: through its separable factors)
+CURVATURE_SHAPES = {1: (23,), 2: (14, 13), 3: (9, 8, 7), 4: (6, 5, 5, 4)}
+
+
+def _curvature_channels_last(rank):
+    """det(H) / (1 + |∇|²)² on channel-last [∇ | vec(H)] values, with
+    ``jnp.linalg.det`` — the default pointwise contract."""
+
+    def fn(D):
+        g = D[..., :rank]
+        H = D[..., rank:].reshape(D.shape[:-1] + (rank, rank))
+        return jnp.linalg.det(H) / (1.0 + jnp.sum(g * g, axis=-1)) ** 2
+
+    return fn
+
+
+@pytest.mark.parametrize("rank", sorted(CURVATURE_SHAPES))
+def test_curvature_methods_agree_batched_and_not(rank):
+    """The channels-first combine equals the channel-last det formula on
+    every path, and the paths agree with each other."""
     rng = np.random.RandomState(5)
-    x = jnp.asarray(rng.randn(14, 13).astype(np.float32))
-    xb = jnp.asarray(rng.randn(BATCH, 14, 13).astype(np.float32))
+    shape = CURVATURE_SHAPES[rank]
+    x = jnp.asarray(rng.randn(*shape).astype(np.float32))
+    xb = jnp.asarray(rng.randn(BATCH, *shape).astype(np.float32))
+    fn, W = _curvature_channels_last(rank), curvature_bank(rank)
+    last = np.asarray(pipe(x).bank((3,) * rank, W).pointwise(fn).run(
+        method="materialize"))
+    last_b = np.asarray(pipe.batched(xb).bank((3,) * rank, W).pointwise(
+        fn).run(method="materialize"))
     ref = np.asarray(gaussian_curvature(x, method="materialize"))
     ref_b = np.asarray(gaussian_curvature(xb, method="materialize",
                                           batched=True))
-    for method in ("lax", "fused"):
-        np.testing.assert_allclose(
-            np.asarray(gaussian_curvature(x, method=method)), ref,
-            rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(
-            np.asarray(gaussian_curvature(xb, method=method, batched=True)),
-            ref_b, rtol=1e-4, atol=1e-5)
+    for method in METHODS:
+        got = np.asarray(gaussian_curvature(x, method=method))
+        got_b = np.asarray(gaussian_curvature(xb, method=method,
+                                              batched=True))
+        np.testing.assert_allclose(got, last, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(got_b, last_b, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(got_b, ref_b, rtol=1e-4, atol=1e-5)
 
 
 def test_curvature_fused_never_materializes():

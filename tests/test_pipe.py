@@ -565,6 +565,118 @@ print("sharded-pipe OK")
     assert "sharded-pipe OK" in out
 
 
+# -- channel-major hand-off (DESIGN.md §11, fusion rule 4) -------------------
+
+
+def _handoffs():
+    from repro.obs import REGISTRY
+
+    return REGISTRY.counter("pipe/channel_major_handoff").value
+
+
+def test_curvature_plan_has_no_transpose():
+    """The bank hands its K channels to the channels-first det combine
+    as the convolution computes them: no relayout anywhere in the
+    program.  A default-contract stage still gets them trailing."""
+    from repro.core.filters import gaussian_curvature
+
+    x = jnp.asarray(np.random.RandomState(7).randn(9, 8, 7), jnp.float32)
+    # traced, gaussian_curvature runs the planner's program inline
+    hlo = jax.jit(lambda t: gaussian_curvature(t, method="lax")).lower(
+        x).as_text()
+    assert "stablehlo.transpose" not in hlo
+    W = curvature_bank(3)
+    last = jax.jit(lambda t: pipe(t).bank((3, 3, 3), W).pointwise(
+        lambda D: D[..., 0], key="first").run(method="lax")).lower(
+        x).as_text()
+    assert "stablehlo.transpose" in last
+
+
+@pytest.mark.parametrize("method", METHODS)
+def test_pointwise_layout_contract(method):
+    """Default stages see (..., *spatial, K); channels-first stages see
+    the channels leading, and a channel axis they keep trails again."""
+    x = jnp.asarray(np.random.RandomState(8).randn(7, 6, 5), jnp.float32)
+    W = curvature_bank(3)
+    seen = {}
+
+    def last(D):
+        seen["last"] = D.shape
+        return D * 2.0
+
+    def first(C):
+        seen["first"] = C.shape
+        return C * 2.0
+
+    a = pipe(x).bank((3, 3, 3), W).pointwise(last, key="l").run(
+        method=method)
+    b = pipe(x).bank((3, 3, 3), W).pointwise(
+        first, key="f", channels_first=True).run(method=method)
+    assert seen["last"] == (7, 6, 5, 12)
+    assert seen["first"][0] == 12  # the fused path shows its flat rows
+    assert a.shape == b.shape == (7, 6, 5, 12)
+    np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-6,
+                               atol=1e-6)
+
+
+def test_channels_first_tiled_and_sharded_match():
+    """The tile stream and the sharded step loop give the in-memory
+    answer with a channels-first combine, kept channels included."""
+    from repro.core.filters import _curvature_combine, gaussian_curvature
+
+    x = jnp.asarray(np.random.RandomState(9).randn(12, 10, 9), jnp.float32)
+    W = curvature_bank(3)
+    P = pipe(x).bank((3, 3, 3), W).pointwise(
+        _curvature_combine(3), key="curv", channels_first=True)
+    ref = np.asarray(gaussian_curvature(x, method="lax"))
+    np.testing.assert_allclose(
+        np.asarray(P.run(method="lax", tiles=(2, 2, 1))), ref, rtol=1e-6,
+        atol=1e-7)
+    K = pipe(x).bank((3, 3, 3), W).pointwise(
+        lambda C: C[:3] * C[3:6], key="keep", channels_first=True)
+    np.testing.assert_allclose(
+        np.asarray(K.run(method="lax", tiles=(2, 1, 2))),
+        np.asarray(K.run(method="lax")), rtol=1e-6, atol=1e-7)
+    out = run_with_devices("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from repro.pipe import pipe
+from repro.core.distributed import sharded_pipe_fn
+from repro.core.filters import (_curvature_combine, curvature_bank,
+                                gaussian_curvature)
+
+x = jnp.asarray(np.random.RandomState(9).randn(16, 10, 9), jnp.float32)
+mesh = Mesh(np.array(jax.devices()).reshape(4), ("data",))
+G = (pipe(jax.ShapeDtypeStruct(x.shape, x.dtype))
+     .bank((3, 3, 3), curvature_bank(3))
+     .pointwise(_curvature_combine(3), key="curv", channels_first=True))
+got = jax.jit(sharded_pipe_fn(mesh, "data", G, method="lax",
+                              pad_value="edge"))(x)
+ref = gaussian_curvature(x, method="lax")
+np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6,
+                           atol=1e-7)
+print("sharded-curvature OK")
+""", 4)
+    assert "sharded-curvature OK" in out
+
+
+def test_channel_major_handoff_counter(fresh_cache):
+    """Counted once per plan build that hands a bank's output to a
+    channels-first stage: not on a cache hit, not for other graphs."""
+    from repro.core.filters import gaussian_curvature
+
+    x = jnp.asarray(np.random.RandomState(10).randn(11, 9, 6), jnp.float32)
+    before = _handoffs()
+    gaussian_curvature(x, method="lax").block_until_ready()
+    assert _handoffs() == before + 1
+    gaussian_curvature(x, method="lax").block_until_ready()
+    assert _handoffs() == before + 1
+    jax.block_until_ready(
+        pipe(x).gaussian(1.5).gradient().moments(order=2).run(
+            method="lax"))
+    assert _handoffs() == before + 1
+
+
 # -- property-fuzz: the fusion planner (DESIGN.md §11/§12) -------------------
 
 

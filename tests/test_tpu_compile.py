@@ -108,3 +108,24 @@ def test_moment_compiles_at_ct_size(one_chip):
     lowered = ops.fused_moment_sums.lower(
         _sds(shape, one_chip), interpret=False, order=2)
     _check(lowered, shape, (3,))
+
+
+def test_curvature_handoff_relayouts_one_channel(one_chip):
+    """The det combine runs on the K=12 bank's own rows: the loops XLA
+    writes to lay rows out as a volume carry one channel, not twelve."""
+    import re
+
+    from repro.core.filters import _curvature_combine
+
+    grid = make_quasi_grid(CT, (3, 3, 3), 1, "same", 1)
+    lowered = ops.fused_stencil_bank.lower(
+        _sds(CT, one_chip), grid=grid, weight_matrix=_sds((27, 12), one_chip),
+        pad_value="edge", interpret=False, tile_rows=TILE_ROWS,
+        pointwise=_curvature_combine(3))
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    padded = int(np.prod(grid.padded_shape))
+    carried = [int(np.prod([int(d) for d in dims.split(",")]))
+               for line in text.splitlines() if " while(" in line
+               for dims in re.findall(r"f32\[([\d,]+)\]", line)]
+    assert carried and max(carried) <= 1.2 * padded, max(carried) / padded
