@@ -22,11 +22,14 @@ spatial-slab) block.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import time
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.grid import make_quasi_grid, normalize_pad_value
@@ -199,9 +202,19 @@ def sharded_pipe_fn(
     batch is embarrassingly parallel), and the fused step program runs
     shard-locally with exactly **one halo exchange per fused group**:
     pointwise stages and the terminal reduction ride their group's
-    exchange for free.  A terminal ``moments`` tree-merges across the
-    slab axis (per batch item — per-item states stay batch-sharded); a
-    terminal ``hist`` psums its counts.
+    exchange for free.  On its halo-extended slab each group is a
+    'valid' pass planned as the one-chip planner plans a 'valid' group:
+    per-dim 1-D passes where ``separable_factors`` factors its weights
+    and ``separable_profitable`` says they pay, else one dense pass.  A
+    terminal ``moments`` tree-merges across the slab axis (per batch item
+    — per-item states stay batch-sharded); a terminal ``hist`` psums its
+    counts.
+
+    Building records, in ``repro.obs``: the span ``shard/build``
+    (``shards``, ``groups``), the counters ``shard/separable_groups`` and
+    ``shard/halo_exchanges``, and the gauge ``shard/halo_bytes`` — the
+    bytes one call's exchanges send from each device.  A call records
+    nothing.
 
     Restrictions (actionable errors): linear groups must be stride-1
     'same' — slab boundaries must align with grid slices — which also
@@ -211,13 +224,16 @@ def sharded_pipe_fn(
     one linear op and composition happens on-device only.  ``zscore`` /
     ``cov`` stages are not yet routed either.
     """
-    from repro.pipe.compile import _apply_pointwise, _apply_reduce
+    from repro.obs import TRACER, counter, gauge
+    from repro.pipe.compile import (
+        _apply_linear, _apply_pointwise, _apply_reduce,
+    )
     from repro.pipe.fuse import (
         LinearStep, PointwiseStep, ReduceStep, ZscoreStep, build_program,
     )
     from repro.core.plan import ExecOptions
-    from repro.core import engine
 
+    t0 = time.perf_counter_ns()
     batched = batch_axis_name is not None
     if bool(graph.batched) != batched:
         raise ValueError(
@@ -254,11 +270,19 @@ def sharded_pipe_fn(
         raise ValueError(
             f"batch dim {graph.x.shape[0]} not divisible by "
             f"{mesh.shape[batch_axis_name]} batch shards")
-    meth = opts.resolved_method
+    # the slab-local steps read no fill: every group runs on a 'valid'
+    # grid over its halo-extended, in-plane padded slab
+    local_opts = dataclasses.replace(opts, pad_value=0.0)
+    lead = (graph.x.shape[0] // mesh.shape[batch_axis_name],) \
+        if batched else ()
+    slab = ((graph.spatial_shape[0] // n_shards,)
+            + tuple(graph.spatial_shape[1:]))
+    local, halo_bytes = _slab_steps(program.steps, slab, lead, graph.x.dtype,
+                                    local_opts, batched)
 
-    def _local_linear(h, step: LinearStep):
-        """One halo exchange for the whole fused group, then a local
-        'valid' pass over the halo-extended slab."""
+    def _local_linear(h, step: LinearStep, lstep: LinearStep):
+        """One halo exchange for the whole fused group, then its 'valid'
+        pass (or per-dim passes) over the halo-extended slab."""
         grid = step.grid
         halo_lo, halo_hi = grid.halo()[0]
         hh = halo_exchange(h, halo_lo, halo_hi, axis_name, opts.pad_value,
@@ -268,24 +292,15 @@ def sharded_pipe_fn(
                                               grid.pad_hi[1:])])
         if any(p != (0, 0) for p in pads):
             hh = pad_array(hh, pads, opts.pad_value)
-        lshape = hh.shape[1:] if batched else hh.shape
-        lgrid = make_quasi_grid(lshape, grid.op_shape, 1, "valid",
-                                grid.dilation)
-        if step.kind == "stencil":
-            return engine.execute_stencil(
-                hh, lgrid, jnp.asarray(step.weights[:, 0]), 0.0, meth,
-                batched)
-        return engine.execute_stencil_bank(
-            hh, lgrid, jnp.asarray(step.weights), 0.0, meth, batched,
-            pointwise=step.pointwise)
+        return _apply_linear(hh, lstep, local_opts, batched)
 
     out_is_state = program.out_kind != "array"
 
     def local_fn(x_local):
         h = x_local
-        for step in program.steps:
+        for step, lstep in zip(program.steps, local):
             if isinstance(step, LinearStep):
-                h = _local_linear(h, step)
+                h = _local_linear(h, step, lstep)
             elif isinstance(step, PointwiseStep):
                 h = _apply_pointwise(h, step, batched, rank)
             elif isinstance(step, ReduceStep):
@@ -315,10 +330,65 @@ def sharded_pipe_fn(
         out_spec = P(*(tuple(in_spec) + (None,)))
     else:
         out_spec = in_spec
-    return jax.shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
         check_vma=False,
     )
+    groups = [s for s in local if isinstance(s, LinearStep)]
+    counter("shard/separable_groups").inc(
+        sum(1 for s in groups if s.factors is not None))
+    counter("shard/halo_exchanges").inc(
+        sum(1 for s in program.steps if isinstance(s, LinearStep)
+            and sum(s.grid.halo()[0]) > 0))
+    gauge("shard/halo_bytes").set(halo_bytes)
+    TRACER.record("shard/build", time.perf_counter_ns() - t0,
+                  shards=n_shards, groups=len(groups))
+    return fn
+
+
+def _slab_steps(steps, slab, lead, dtype, local_opts, batched):
+    """Each step as it runs on one device's slab, and the bytes one
+    call's halo exchanges send from that device.
+
+    A linear group's 'same' grid becomes a 'valid' grid over the slab
+    extended by its leading-dim halo and padded in-plane, planned as the
+    one-chip planner plans a 'valid' group (``fuse._plan_linear``): the
+    per-dim rewrite where it is exact and pays.  The value entering each
+    step is followed as a shape (``jax.eval_shape`` on the ``lax`` path,
+    which runs no kernel), so channels and dtypes count as they are."""
+    from repro.pipe.compile import _apply_linear, _apply_pointwise
+    from repro.pipe.fuse import LinearStep, PointwiseStep, _plan_linear
+
+    rank = len(slab)
+    shape_opts = dataclasses.replace(local_opts, method="lax")
+    h = jax.ShapeDtypeStruct(tuple(lead) + tuple(slab), dtype)
+    sdim = len(lead)
+    out, sent = [], 0
+    for step in steps:
+        if isinstance(step, LinearStep):
+            g = step.grid
+            lo, hi = g.halo()[0]
+            plane = int(np.prod(h.shape)) // h.shape[sdim]
+            sent += (lo + hi) * plane * jnp.dtype(h.dtype).itemsize
+            ext = tuple(n + a + b for n, a, b in zip(
+                h.shape[sdim:sdim + rank], (lo,) + g.pad_lo[1:],
+                (hi,) + g.pad_hi[1:]))
+            lstep = dataclasses.replace(
+                _plan_linear(g.op_shape, step.weights, step.kind, ext,
+                             g.stride, "valid", g.dilation, 0.0,
+                             step.fused_from, try_separable=True),
+                pointwise=step.pointwise)
+            h = jax.eval_shape(
+                lambda t, s=lstep: _apply_linear(t, s, shape_opts, batched),
+                jax.ShapeDtypeStruct(h.shape[:sdim] + ext
+                                     + h.shape[sdim + rank:], h.dtype))
+            out.append(lstep)
+            continue
+        if isinstance(step, PointwiseStep):
+            h = jax.eval_shape(
+                lambda t, s=step: _apply_pointwise(t, s, batched, rank), h)
+        out.append(step)
+    return tuple(out), sent
 
 
 # -- out-of-core tile streams (DESIGN.md §12) --------------------------------

@@ -177,6 +177,62 @@ def test_same_split_plan_shape(rng):
     assert step.melt_calls == step.interior.melt_calls + 4 * 2
 
 
+#: the one-chip programs of the CT benchmark cells' graphs at their size
+#: (256×512×512, 'edge'): the sharded front end plans its own slabs and
+#: must leave these, and the plan keys they intern under, as they are
+CT_PLANS = {
+    "ct-same-variance": (
+        "split[9x9x9,K=3,slabs=6,fused=2] -> reduce[moments] | passes=1 "
+        "melt_calls(materialize)=15",
+        (("stencil", (7, 7, 7), (1, 1, 1), "same", (1, 1, 1), 1,
+          "8553cbe791f3b9ec"),
+         ("bank", (3, 3, 3), (1, 1, 1), "same", (1, 1, 1), 3,
+          "4ba62108e01fb53f"),
+         ("moments", 2, None))),
+    "ct-curvature": (
+        "linear[3x3x3,K=12,dense,fused=1]+pointwise | passes=1 "
+        "melt_calls(materialize)=1",
+        (("bank", (3, 3, 3), (1, 1, 1), "same", (1, 1, 1), 12,
+          "fb70cc1097ab2d27"),
+         ("ptw", "curv", True))),
+}
+
+
+def _ct_graph(name):
+    from repro.core.filters import _curvature_combine, curvature_bank
+
+    t = jax.ShapeDtypeStruct((256, 512, 512), jnp.float32)
+    if name == "ct-same-variance":
+        return pipe(t).gaussian(1.5).gradient().moments(order=2)
+    return pipe(t).bank((3, 3, 3), curvature_bank(3)).pointwise(
+        _curvature_combine(3), key="curv", channels_first=True)
+
+
+@pytest.mark.parametrize("method", ("lax", "fused"))
+@pytest.mark.parametrize("name", sorted(CT_PLANS))
+def test_one_chip_plans_of_the_ct_graphs_are_fixed(name, method):
+    from repro.core.plan import ExecOptions
+    from repro.pipe.compile import plan_key_for
+    from repro.pipe.fuse import build_program
+
+    G = _ct_graph(name)
+    prog = build_program(G, ExecOptions.make(method, "edge", False))
+    described, signature = CT_PLANS[name]
+    assert prog.describe() == described
+    assert plan_key_for(G, method, "edge")[-1] == signature
+    if name == "ct-same-variance":
+        (split, _) = prog.steps
+        # the composed interior runs per-dim; its slabs replay the
+        # stages dense
+        assert split.interior.factors is not None
+        assert split.interior.grid.padding == "valid"
+        assert split.interior_lo == (4, 4, 4)
+        assert split.inner.describe() == (
+            "linear[7x7x7,K=1,dense,fused=1] -> "
+            "linear[3x3x3,K=3,dense,fused=1] | passes=2 "
+            "melt_calls(materialize)=2")
+
+
 @pytest.mark.parametrize("method", ("lax", "materialize"))
 def test_same_split_boundary_bit_identical(method, rng):
     """Where the boundary slabs replay the per-stage program, the split
