@@ -45,6 +45,7 @@ from repro.core.grid import (
     make_quasi_grid,
     normalize_pad_value,
     normalize_tuple,
+    pass_grids,
 )
 from repro.core.melt import melt, pad_array, unmelt
 from repro.core.plan import (
@@ -282,24 +283,14 @@ def execute_separable_bank(x, grid: QuasiGrid, factors, pad_value,
     passes and the intermediate shapes walk from ``in_shape`` down to
     ``out_shape``.  ``pointwise`` is as for ``execute_stencil_bank``.
     """
-    rank = grid.rank
-
-    def grid1(i, cur_shape):
-        op1 = tuple(grid.op_shape[j] if j == i else 1 for j in range(rank))
-        s1 = tuple(grid.stride[j] if j == i else 1 for j in range(rank))
-        return make_quasi_grid(cur_shape, op1, s1, grid.padding,
-                               grid.dilation)
-
-    grids = [grid1(0, grid.in_shape)]
-    for i in range(1, rank):
-        grids.append(grid1(i, grids[-1].out_shape))
     if method == "fused":
         from repro.kernels import melt_stencil_ops  # lazy: kernels optional
 
         out = melt_stencil_ops.fused_separable_bank(
-            x, tuple(grids), tuple(factors),
+            x, grid, tuple(factors),
             pad_value=normalize_pad_value(pad_value), batched=batched)
     else:
+        grids = pass_grids(grid)
         out = execute_stencil_bank(x, grids[0], factors[0], pad_value,
                                    method, batched)
         for g, f in zip(grids[1:], factors[1:]):
@@ -307,7 +298,7 @@ def execute_separable_bank(x, grid: QuasiGrid, factors, pad_value,
                                             batched)
     if pointwise is None:
         return out
-    return apply_channels_first(pointwise, out, rank, batched)
+    return apply_channels_first(pointwise, out, grid.rank, batched)
 
 
 #: memoized factorization results keyed on (weight bytes, dtype, shape, op

@@ -30,6 +30,7 @@ __all__ = [
     "grid_shape",
     "neighborhood_offsets",
     "make_quasi_grid",
+    "pass_grids",
     "stage_footprint",
     "compose_footprints",
     "chain_same_margins",
@@ -314,3 +315,20 @@ def make_quasi_grid(
         pad_lo=pads[0],
         pad_hi=pads[1],
     )
+
+
+def pass_grids(grid: QuasiGrid) -> Tuple[QuasiGrid, ...]:
+    """The 1-D grids of ``grid``'s separable rewrite: pass ``i`` applies
+    dim ``i``'s operator extent and stride to the previous pass's output
+    (the first to ``grid.in_shape``), so the shapes walk to
+    ``grid.out_shape``."""
+    out, shape = [], grid.in_shape
+    for i in range(grid.rank):
+        g = make_quasi_grid(
+            shape, [grid.op_shape[i] if j == i else 1
+                    for j in range(grid.rank)],
+            [grid.stride[i] if j == i else 1 for j in range(grid.rank)],
+            grid.padding, grid.dilation)
+        out.append(g)
+        shape = g.out_shape
+    return tuple(out)

@@ -7,6 +7,8 @@ modes.  Separable execution must be indistinguishable from the dense bank
 wherever it engages; the fused path must never materialize ``M``; and bank
 signatures must intern in the plan cache.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -144,6 +146,66 @@ def test_separable_K1_and_dilation_regression():
                                  separable=True)
         np.testing.assert_allclose(np.asarray(sep), np.asarray(dense),
                                    rtol=1e-5, atol=1e-6)
+
+
+def _per_pass_reference(x, grid, factors, pad_value, batched):
+    """The separable bank as it ran before rows were kept: pad, lay out,
+    filter and crop around every 1-D pass."""
+    from repro.core.grid import make_quasi_grid
+    from repro.kernels import ops
+
+    rank, h, shape = grid.rank, x, grid.in_shape
+    for d, f in enumerate(factors):
+        g = make_quasi_grid(shape, [grid.op_shape[d] if i == d else 1
+                                    for i in range(rank)], 1, grid.padding)
+        run = ops.fused_stencil_bank if d == 0 else ops.fused_stencil_depthwise
+        h = run(h, g, jnp.asarray(f), pad_value, interpret=True,
+                batched=batched)
+        shape = g.out_shape
+    return h
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "batch"])
+@pytest.mark.parametrize("width", [38, 128])
+@pytest.mark.parametrize("shape,op", [((9,), (5, 3)), ((6, 5), (3, 5, 3))],
+                         ids=["r2", "r3"])
+@pytest.mark.parametrize("K", [1, 3])
+@pytest.mark.parametrize("padding,pad_value",
+                         [("valid", 0.0), ("same", "edge"), ("same", 0.0),
+                          ("same", "reflect")],
+                         ids=["valid", "edge", "zero", "reflect"])
+def test_separable_rows_kept_is_bitwise_per_pass(padding, pad_value, K, shape,
+                                                 op, width, batched):
+    """The fused separable bank pads once, keeps the kernels' rows across
+    its per-dim passes and crops once — for every 'valid' group, and for
+    'same' where the pad keeps the planes' lane alignment (here the
+    misaligned width): bit for bit what padding, laying out and cropping
+    around each pass gives, and the dense bank's values within the bank
+    tolerances."""
+    from repro.core.grid import make_quasi_grid
+    from repro.kernels import ops
+
+    spatial = shape + (width,)
+    rank = len(spatial)
+    rng = np.random.RandomState(rank * 100 + width + K)
+    x = jnp.asarray(rng.randn(*((BATCH,) * batched + spatial)), jnp.float32)
+    factors = tuple(rng.randn(k, K).astype(np.float32) for k in op)
+    grid = make_quasi_grid(spatial, op, 1, padding)
+    assert ops._keeps_rows(grid) == (padding == "valid" or width == 38)
+    got = ops.fused_separable_bank(x, grid, tuple(map(jnp.asarray, factors)),
+                                   pad_value, interpret=True, batched=batched)
+    want = _per_pass_reference(x, grid, factors, pad_value, batched)
+    assert got.shape == want.shape == (
+        (BATCH,) * batched + grid.out_shape + (K,))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    W = np.stack([functools.reduce(np.multiply.outer,
+                                   [f[:, k] for f in factors]).ravel()
+                  for k in range(K)], axis=1)
+    dense = apply_stencil_bank(x, op, jnp.asarray(W), padding=padding,
+                               method="lax", pad_value=pad_value,
+                               separable=False, batched=batched)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dense),
+                               rtol=2e-4, atol=2e-5)
 
 
 def test_separable_detection():
@@ -299,6 +361,37 @@ def test_curvature_fused_never_materializes():
     assert melt_call_count() == before  # fresh shape → fresh trace, 0 melts
     jax.block_until_ready(gaussian_curvature(x, method="materialize"))
     assert melt_call_count() > before  # the oracle path still melts
+
+
+def _rows_kept():
+    from repro.obs import REGISTRY
+
+    return REGISTRY.counter("kernels/separable_rows_kept").value
+
+
+@pytest.mark.parametrize("shape,kept", [((14, 13), 1), ((9, 7, 5), 2),
+                                        ((6, 4, 128), 0)],
+                         ids=["r2", "r3", "r3-lane-aligned"])
+def test_separable_rows_kept_counter(shape, kept):
+    """rank − 1 per traced fused separable group that keeps its rows (each
+    pass after the first takes its predecessor's rows), 0 where a 'same'
+    pad would misalign a lane-aligned plane; nothing on a re-run of the
+    same trace, nor for a dense bank such as the curvature bank."""
+    clear_plan_cache()
+    rank = len(shape)
+    x = jnp.asarray(np.random.RandomState(11).randn(*shape), jnp.float32)
+    gw = gaussian_weights((5,) * rank, 1.2)
+    W = jnp.stack([gw, -gw], axis=1)
+    before = _rows_kept()
+    jax.block_until_ready(apply_stencil_bank(x, 5, W, method="fused",
+                                             separable=True))
+    assert _rows_kept() == before + kept
+    jax.block_until_ready(apply_stencil_bank(x, 5, W, method="fused",
+                                             separable=True))
+    assert _rows_kept() == before + kept
+    jax.block_until_ready(apply_stencil_bank(x, 3, curvature_bank(rank),
+                                             method="fused"))
+    assert _rows_kept() == before + kept
 
 
 def test_difference_stencils_cached_and_readonly():

@@ -29,6 +29,9 @@ from repro.kernels import ops
 
 #: the chip smoke's CT study: 256 slices of 512×512
 CT = (256, 512, 512)
+#: one chip's slab of the four-chip RM state: 80 slices and a 3-plane
+#: halo on each side, the 2048×2048 plane padded for a 7-tap Gaussian
+RM_SLAB = (86, 2054, 2054)
 TILE_ROWS = 256
 #: argument + output + temp bytes over the call's own input + output
 #: bytes; a one-lane operand relaid out to 128 lanes would be ~128
@@ -108,6 +111,44 @@ def test_moment_compiles_at_ct_size(one_chip):
     lowered = ops.fused_moment_sums.lower(
         _sds(shape, one_chip), interpret=False, order=2)
     _check(lowered, shape, (3,))
+
+
+def _volume_loops(text, numel):
+    """The ``while`` loops XLA writes to relay out an f32 array of at
+    least ``numel`` elements (a volume, not a plane)."""
+    import re
+
+    return [line for line in text.splitlines() if " while(" in line
+            and any(int(np.prod([int(d) for d in dims.split(",")])) >= numel
+                    for dims in re.findall(r"f32\[([\d,]+)\]", line))]
+
+
+@pytest.mark.parametrize("shape,taps,K,padding,loops,temp_gib", [
+    (RM_SLAB, 7, 1, "valid", 2, 6.125),
+    (CT, 9, 3, "valid", 0, 1.6),
+    (CT, 9, 3, "same", 0, 1.75),
+], ids=["rm-slab", "ct-interior", "ct-same"])
+def test_separable_group_keeps_rows_across_passes(one_chip, shape, taps, K,
+                                                  padding, loops, temp_gib):
+    """A 'valid' separable group is laid out as the kernels' rows once and
+    back once, not around each of its three per-dim passes: at the RM
+    slab's lane-misaligned width that leaves 2 volume relayout loops (4
+    when each pass had its own pair), and at the lane-aligned CT interior
+    it halves the temporaries (2.91 GiB with a crop and re-pad between
+    passes).  A 'same' group around the lane-aligned CT plane keeps a pad
+    and crop per pass: one padded volume's 520×520 planes would cost a
+    relayout loop and 3.06 GiB of temporaries against 1.70."""
+    grid = make_quasi_grid(shape, (taps,) * 3, 1, padding, 1)
+    lowered = ops.fused_separable_bank.lower(
+        _sds(shape, one_chip), grid=grid,
+        factors=tuple(_sds((taps, K), one_chip) for _ in range(3)),
+        pad_value="edge" if padding == "same" else 0.0, interpret=False)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert len(_volume_loops(text, int(np.prod(shape)))) <= loops
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= temp_gib * 2 ** 30, temp / 2 ** 30
 
 
 def test_curvature_handoff_relayouts_one_channel(one_chip):
