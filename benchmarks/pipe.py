@@ -205,20 +205,6 @@ def main(argv=None):
         print(f"FATAL,strided chain planned {prog_str.passes} passes, "
               f"want 1 (composed stride-4)")
         return 2
-    # measured tile autotuning engages on the fused path (DESIGN.md §16):
-    # one fused run must intern at least one TunePlan, unless the env
-    # opt-out pinned the heuristic
-    from repro.kernels.melt_stencil import autotune_enabled
-
-    if autotune_enabled():
-        jax.block_until_ready(
-            pipe(small).gaussian(SIGMA, op_shape=3).gradient()
-            .run(method="fused", pad_value="edge"))
-        if plan_cache_stats()["kinds"]["tune"] < 1:
-            print("FATAL,fused run interned no TunePlan with autotuning "
-                  "enabled")
-            return 2
-
     rows, speedup = headline_rows(x, reps)
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")

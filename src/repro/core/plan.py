@@ -42,7 +42,7 @@ from repro.core.grid import (
 from repro.obs.trace import TRACER as _TRACER, span as _span
 from repro.runtime import compile_cache as _compile_cache
 
-# count the compiles of plans' first dispatches (and the tuner's)
+# count the compiles of plans' first dispatches
 _compile_cache.install()
 
 __all__ = [
@@ -52,13 +52,11 @@ __all__ = [
     "StatsPlan",
     "PipePlan",
     "TilePlan",
-    "TunePlan",
     "get_plan",
     "get_bank_plan",
     "get_stats_plan",
     "get_pipe_plan",
     "get_tile_plan",
-    "get_tune_plan",
     "normalize_axes",
     "separable_eligible",
     "plan_cache_stats",
@@ -202,7 +200,7 @@ def _plan_kind(key: tuple) -> str:
     tag = key[0]
     if tag == "tiled":
         return "tile"
-    if tag in ("bank", "stats", "pipe", "tune"):
+    if tag in ("bank", "stats", "pipe"):
         return tag
     return "stencil"
 
@@ -733,51 +731,6 @@ def get_tile_plan(key: tuple, build) -> TilePlan:
     return _intern(("tiled",) + tuple(key), build)
 
 
-class TunePlan:
-    """A measured kernel-tuning decision, interned like any other plan.
-
-    Holds the winning ``tile_rows`` for one canonical kernel problem —
-    keyed ``("tune", backend, family, numel, c_in, c_out, dtype)`` by
-    ``repro.kernels.melt_stencil.tuned_tile_rows`` — plus the candidate
-    set and per-candidate timings for inspection.  Interning in the
-    shared LRU gives the tuner the plan-cache contract for free: one
-    measurement per key (stampede-latched), hits thereafter, LRU
-    eviction, and a ``kinds["tune"]`` row in :func:`plan_cache_stats`.
-    """
-
-    __slots__ = ("key", "tile_rows", "candidates", "timings_us", "_hits")
-    kind = "tune"
-
-    def __init__(self, key: tuple, tile_rows: int, candidates, timings_us):
-        self.key = key
-        self.tile_rows = int(tile_rows)
-        self.candidates = tuple(candidates)
-        self.timings_us = tuple(timings_us)
-        self._hits = 0
-
-    def __hash__(self):
-        return hash(self.key)
-
-    def __eq__(self, other):
-        return isinstance(other, TunePlan) and self.key == other.key
-
-    def __repr__(self):
-        pairs = ", ".join(f"{c}:{t:.0f}us" for c, t in
-                          zip(self.candidates, self.timings_us))
-        return f"TunePlan(tile_rows={self.tile_rows}, measured={{{pairs}}})"
-
-    def stats(self) -> Dict[str, int]:
-        return {"hits": self._hits}
-
-
-def get_tune_plan(key: tuple, build) -> TunePlan:
-    """Intern a kernel-tuning decision under ``("tune", *key)`` in the
-    shared LRU cache — measured autotuning is served (and evicted) by the
-    same machinery as every other plan kind, so a key is measured once
-    per process and every later request is a cache hit."""
-    return _intern(("tune",) + tuple(key), build)
-
-
 def plan_fingerprint(*parts) -> str:
     """Stable hex digest of a nested plan-key structure.
 
@@ -812,10 +765,9 @@ def plan_fingerprint(*parts) -> str:
 def plan_cache_stats() -> Dict[str, object]:
     """Process-wide counters: ``size``, ``hits``, ``misses``, ``evictions``,
     plus a per-kind resident-plan breakdown under ``"kinds"`` (how many of
-    the ``size`` plans are stencil / bank / stats / pipe / tile / tune)."""
+    the ``size`` plans are stencil / bank / stats / pipe / tile)."""
     with _LOCK:
-        kinds = {"stencil": 0, "bank": 0, "stats": 0, "pipe": 0, "tile": 0,
-                 "tune": 0}
+        kinds = {"stencil": 0, "bank": 0, "stats": 0, "pipe": 0, "tile": 0}
         for key in _CACHE:
             kinds[_plan_kind(key)] += 1
         return {"size": len(_CACHE), **_GLOBAL, "kinds": kinds}

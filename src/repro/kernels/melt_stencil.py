@@ -46,18 +46,13 @@ the chip in tests/test_tpu_compile.py.
 from __future__ import annotations
 
 import functools
-import os
-import threading
-import time
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.runtime import compile_cache
 
 #: lane width of one TPU vector register; the canonical row width
 LANES = 128
@@ -109,151 +104,6 @@ def pick_tile_rows(numel: int, c_in: int, c_out: int, dtype,
     fixed = row * (int(span_rows) + 2 * _CHUNK * int(windows))
     t = ((budget - fixed) // per_row // sub) * sub
     return int(max(sub, min(t, _MAX_TILE_ROWS)))
-
-
-# -- measured tile autotuning (DESIGN.md §16) --------------------------------
-#
-# ``tile_rows=None`` means *measured*: time a few sublane-aligned
-# candidates around the heuristic on a synthetic canonical problem,
-# intern the winner as a ``TunePlan`` in the shared plan LRU (one
-# measurement per key, hits thereafter), and fall back to the heuristic
-# when the opt-out env pins it.  Measurement timings are hardware facts,
-# not plan state, so they also live in a process-lifetime memo — a
-# ``clear_plan_cache()`` re-interns the TunePlan from the memo instead of
-# re-timing the kernels.  Whatever the source, the wrapper caps the tile
-# at what fits VMEM for the call's own window geometry.
-#
-# ``fused_moment_rows`` deliberately keeps a fixed tile: its tile size
-# shapes the Chan merge tree's numerics and must mirror
-# ``moment_tile_counts`` exactly, so a measured (cache-dependent) size
-# would change results and break the static count mirror.
-
-#: set to "0"/"false"/"off" to pin the pick_tile_rows heuristic
-_AUTOTUNE_ENV = "REPRO_TILE_AUTOTUNE"
-
-#: (backend, family, numel, c_in, c_out, dtype) → (candidates, timings_us);
-#: survives plan-cache clears so a key is never re-measured in-process
-_TUNE_MEMO: dict = {}
-
-
-def autotune_enabled() -> bool:
-    return (os.environ.get(_AUTOTUNE_ENV, "1").strip().lower()
-            not in ("0", "false", "off"))
-
-
-def _tile_candidates(numel: int, c_in: int, c_out: int, dtype
-                     ) -> Tuple[int, ...]:
-    """Sublane-aligned candidate set bracketing the heuristic (¼×–2×)."""
-    base = pick_tile_rows(numel, c_in, c_out, dtype)
-    sub = max(_SUBLANES.get(jnp.dtype(dtype).itemsize, 8), _CHUNK)
-    cands = []
-    for t in (base // 4, base // 2, base, 2 * base):
-        t = max(sub, min((t // sub) * sub, _MAX_TILE_ROWS))
-        if t not in cands:
-            cands.append(t)
-    return tuple(cands)
-
-
-def _measure_candidates(family: str, numel: int, c_in: int, c_out: int,
-                        dtype, candidates: Tuple[int, ...]) -> list:
-    """Wall-time each candidate on a synthetic canonical problem (µs).
-
-    The synthetic block is a few grid steps at the largest candidate, in
-    the kernels' own ``(B, C, flat)`` layout with one window of ``numel``
-    consecutive taps — big enough that the per-step tile shape (what
-    ``tile_rows`` controls) dominates, small enough that first-use tuning
-    stays a few kernel compiles.  One warm-up call per candidate absorbs
-    the compile; the min of the timed reps is the score.
-    """
-    if family not in ("stencil", "bank", "depthwise"):
-        raise ValueError(f"unknown tune family {family!r}")
-    interpret = jax.default_backend() != "tpu"
-    c_in = 1 if family != "depthwise" else c_in
-    out_len = 2 * max(candidates) * LANES
-    w = jnp.full((numel, c_out), 1.0 / numel, jnp.float32)
-    offs = tuple(range(numel))
-    x = (jnp.arange(c_in * out_len, dtype=jnp.float32) % 7.0).astype(
-        dtype).reshape(1, c_in, out_len)
-
-    timings = []
-    for cand in candidates:
-        f = jax.jit(functools.partial(
-            fused_melt_rows, weights=w, offsets=offs, tile_rows=cand,
-            interpret=interpret, family=family))
-        f(x).block_until_ready()  # compile + warm-up
-        best = float("inf")
-        for _ in range(2):
-            t0 = time.perf_counter()
-            f(x).block_until_ready()
-            best = min(best, time.perf_counter() - t0)
-        timings.append(best * 1e6)
-    return timings
-
-
-def tuned_tile_rows(family: str, numel: int, c_in: int, c_out: int,
-                    dtype) -> int:
-    """The measured ``tile_rows`` for one canonical kernel problem.
-
-    ``c_in`` counts input channels and ``c_out`` output channels (stencil
-    1/1, bank 1/K, depthwise K/K).  Keyed ``(backend, family, numel,
-    c_in, c_out, dtype)`` and interned as a
-    :class:`~repro.core.plan.TunePlan` in the shared plan LRU: the first
-    request times the :func:`_tile_candidates` set and memoizes the
-    winner; every later request (and every re-intern after a cache clear)
-    is a lookup.  With ``REPRO_TILE_AUTOTUNE=0`` (or an explicit
-    ``tile_rows=`` at the call site) the :func:`pick_tile_rows` heuristic
-    is pinned and nothing is measured.  Safe at trace time: the entry
-    points call this while an enclosing jit is tracing, so measurement
-    runs on a worker thread — JAX trace state is thread-local, meaning
-    the synthetic candidate runs compile and execute concretely there
-    instead of staging into (or crashing under) the caller's trace.
-    """
-    numel, c_in, c_out = int(numel), max(int(c_in), 1), max(int(c_out), 1)
-    if not autotune_enabled():
-        return pick_tile_rows(numel, c_in, c_out, dtype)
-    from repro.core.plan import TunePlan, get_tune_plan  # deferred: cycle
-
-    dtname = jnp.dtype(dtype).name
-    key = (jax.default_backend(), family, numel, c_in, c_out, dtname)
-
-    def build():
-        memo = _TUNE_MEMO.get(key)
-        if memo is None:
-            cands = _tile_candidates(numel, c_in, c_out, dtype)
-            if len(cands) == 1:
-                timings = [0.0]
-            else:
-                box: dict = {}
-
-                def worker():
-                    t0 = time.perf_counter_ns()
-                    try:
-                        with compile_cache.owned("tune"):
-                            box["t"] = _measure_candidates(
-                                family, numel, c_in, c_out, dtype, cands)
-                    except BaseException as e:  # re-raised on the caller
-                        box["e"] = e
-                        return
-                    compile_cache.record_tune(
-                        t0, family=family, numel=numel, c_in=c_in,
-                        c_out=c_out,
-                        tile_rows=cands[int(np.argmin(box["t"]))])
-
-                th = threading.Thread(target=worker, name="repro-tile-tune")
-                # the caller is usually tracing its executor: the wait is
-                # the tuner's time, not the trace's
-                with compile_cache.waiting():
-                    th.start()
-                    th.join()
-                if "e" in box:
-                    raise box["e"]
-                timings = box["t"]
-            memo = _TUNE_MEMO[key] = (cands, tuple(timings))
-        cands, timings = memo
-        winner = cands[int(np.argmin(timings))]
-        return TunePlan(("tune",) + key, winner, cands, timings)
-
-    return get_tune_plan(key, build).tile_rows
 
 
 # -- the linear melt kernel --------------------------------------------------
@@ -326,7 +176,7 @@ def _melt_kernel(w_ref, x_hbm, o_ref, slab, sems, *, taps, windows,
 
 def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
                     tile_rows: Optional[int] = None,
-                    interpret: bool = True, family: str = "bank"):
+                    interpret: bool = True):
     """The canonical linear melt pass, every family.
 
     x: ``(B, C, P)`` flat padded volumes, ``P`` a multiple of 128 (whole
@@ -334,9 +184,9 @@ def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
     channel ``c`` sums ``x[b, c, p + offsets[t]]`` over taps ``t``, reading
     zeros outside ``[0, P)``; weights ``(numel, C·kper)`` give output
     channel ``c·kper + k`` the weight ``weights[t, c·kper + k]`` on tap
-    ``t``.  Returns ``(B, C·kper, P)`` float32.  ``tile_rows=None`` is
-    measured per ``family`` (:func:`tuned_tile_rows`); any tile is capped
-    at what fits the VMEM budget for this call's window geometry.
+    ``t``.  Returns ``(B, C·kper, P)`` float32.  ``tile_rows=None`` means
+    :func:`pick_tile_rows` for these channels; any tile is capped at what
+    fits the VMEM budget for this call's window geometry.
     """
     B, C, P = x.shape
     offsets = tuple(int(o) for o in offsets)
@@ -353,7 +203,7 @@ def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
     front = -(-max(0, -min(offsets)) // LANES)
     shifted = tuple(o + front * LANES for o in offsets)
     if tile_rows is None:
-        tile_rows = tuned_tile_rows(family, numel, C, wcols, x.dtype)
+        tile_rows = pick_tile_rows(numel, C, wcols, x.dtype)
     out_rows = P // LANES
     T = max(_CHUNK, min(_round_up(tile_rows, _CHUNK),
                         _round_up(out_rows, _CHUNK)))

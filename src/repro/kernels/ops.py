@@ -74,15 +74,14 @@ def _flat(xc, grid: QuasiGrid, pad_value):
     return xp.reshape(B, C, -1)
 
 
-def _melt_rows(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
-               interpret):
+def _melt_rows(xc, grid: QuasiGrid, W, pad_value, tile_rows, interpret):
     """(B, C, *spatial) → (B, C·kper, rows, 128) float32: the linear
     kernel's own output, every position of the padded flat volume."""
     _check_fused_grid(grid)
     interpret = _interpret_default() if interpret is None else interpret
     rows = _ms.fused_melt_rows(_flat(xc, grid, pad_value), jnp.asarray(W),
                                grid.flat_offsets(), tile_rows=tile_rows,
-                               interpret=interpret, family=family)
+                               interpret=interpret)
     return rows.reshape(xc.shape[0], rows.shape[1], -1, _ms.LANES)
 
 
@@ -101,11 +100,10 @@ def _crop_rows(rows, grid: QuasiGrid):
     return out[(slice(None),) * len(lead) + _valid_slices(grid)]
 
 
-def _melt(xc, grid: QuasiGrid, W, pad_value, family: str, tile_rows,
-          interpret):
+def _melt(xc, grid: QuasiGrid, W, pad_value, tile_rows, interpret):
     """(B, C, *spatial) → (B, C·kper, *out_shape) float32 through the one
     linear kernel: pad, flatten, every-position pass, crop."""
-    return _crop_rows(_melt_rows(xc, grid, W, pad_value, family, tile_rows,
+    return _crop_rows(_melt_rows(xc, grid, W, pad_value, tile_rows,
                                  interpret), grid)
 
 
@@ -119,14 +117,12 @@ def fused_stencil(x, grid: QuasiGrid, weights, pad_value=0.0,
 
     ``batched=True``: leading dim of ``x`` is a stack of independent tensors;
     the Pallas grid gains a batch axis (one kernel launch for the stack).
-    ``tile_rows=None`` means *measured*: the first use of a kernel-shape
-    key times a few sublane-aligned candidates and interns the winner
-    (``tuned_tile_rows``, DESIGN.md §16); ``REPRO_TILE_AUTOTUNE=0`` pins
-    the ``pick_tile_rows`` VMEM-budget heuristic instead.
+    ``tile_rows=None`` sizes the tile from the VMEM budget
+    (``pick_tile_rows``, DESIGN.md §16).
     """
     xb = x if batched else x[None]
     out = _melt(xb[:, None], grid, jnp.reshape(weights, (-1, 1)), pad_value,
-                "stencil", tile_rows, interpret)[:, 0]
+                tile_rows, interpret)[:, 0]
     return (out if batched else out[0]).astype(x.dtype)
 
 
@@ -142,8 +138,8 @@ def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
     ``weight_matrix`` is (numel(m), K); each grid step reads the input
     windows of one output tile once and accumulates all K operators from
     them, so the window load is amortized across the bank and ``M``
-    never exists in HBM.  ``tile_rows=None`` is measured per kernel-shape
-    key (``tuned_tile_rows``, DESIGN.md §16).
+    never exists in HBM.  ``tile_rows=None`` sizes the tile from the
+    VMEM budget (``pick_tile_rows``, DESIGN.md §16).
 
     ``pointwise`` is an elementwise function of channel-major values,
     (..., K, *spatial) → (..., [K',] *spatial).  It runs on the kernel's
@@ -154,11 +150,11 @@ def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
     """
     xb = x if batched else x[None]
     if pointwise is None:
-        out = _melt(xb[:, None], grid, weight_matrix, pad_value, "bank",
-                    tile_rows, interpret)
+        out = _melt(xb[:, None], grid, weight_matrix, pad_value, tile_rows,
+                    interpret)
         out = jnp.moveaxis(out, 1, -1)
         return (out if batched else out[0]).astype(x.dtype)
-    rows = _melt_rows(xb[:, None], grid, weight_matrix, pad_value, "bank",
+    rows = _melt_rows(xb[:, None], grid, weight_matrix, pad_value,
                       tile_rows, interpret).astype(x.dtype)
     B, K, R = rows.shape[:3]
     flat = ((R * _ms.LANES,) if grid.rank == 1
@@ -177,11 +173,11 @@ def fused_stencil_depthwise(xc, grid: QuasiGrid, weights, pad_value=0.0,
                             interpret=None, batched=False, tile_rows=None):
     """Per-lane stencil: lane k of ``xc`` (..., *spatial, K) is filtered by
     column k of ``weights`` (numel(m), K) — the separable 1-D pass primitive.
-    ``tile_rows=None`` is measured per kernel-shape key (DESIGN.md §16).
+    ``tile_rows=None`` sizes the tile from the VMEM budget (DESIGN.md §16).
     """
     xb = xc if batched else xc[None]
     out = _melt(jnp.moveaxis(xb, -1, 1), grid, weights, pad_value,
-                "depthwise", tile_rows, interpret)
+                tile_rows, interpret)
     out = jnp.moveaxis(out, 1, -1)
     return (out if batched else out[0]).astype(xc.dtype)
 
@@ -216,15 +212,13 @@ def fused_separable_bank(x, grid: QuasiGrid, factors, pad_value=0.0,
             k, dil = grid.op_shape[d], grid.dilation[d]
             offsets = (np.arange(k) - (k - 1) // 2) * dil * int(strides[d])
             h = _ms.fused_melt_rows(h, jnp.asarray(f), offsets,
-                                    interpret=interpret,
-                                    family="bank" if d == 0 else "depthwise")
+                                    interpret=interpret)
         _counter("kernels/separable_rows_kept").inc(len(factors) - 1)
         out = _crop_rows(h.reshape(h.shape[:2] + (-1, _ms.LANES)), grid)
     else:
         out = xb[:, None]
-        for d, (g, f) in enumerate(zip(pass_grids(grid), factors)):
-            out = _melt(out, g, f, pad_value,
-                        "bank" if d == 0 else "depthwise", None, interpret)
+        for g, f in zip(pass_grids(grid), factors):
+            out = _melt(out, g, f, pad_value, None, interpret)
     out = jnp.moveaxis(out, 1, -1)
     return (out if batched else out[0]).astype(x.dtype)
 

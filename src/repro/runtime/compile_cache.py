@@ -24,34 +24,27 @@ when the phase ends; and the events ``cache_hits`` / ``cache_misses``,
 which fire on that thread before its backend phase ends.
 :func:`install` (run when ``repro.core.plan`` is imported) listens to
 them and counts a compile only when the compiling thread has an
-*owner*:
-
-- a plan's first dispatch (``StencilPlan.__call__`` and kin while the
-  executor has never traced) owns its compiles as the plan's ``kind``;
-- the tile autotuner's worker thread owns its candidate compiles as
-  ``tune``.
+*owner*: a plan's first dispatch (``StencilPlan.__call__`` and kin
+while the executor has never traced) owns its compiles as the plan's
+``kind``.
 
 Compiles with no owner — a caller's own jits, a benchmark's reference —
 are not counted.  Into ``repro.obs.REGISTRY``, always on:
 
 - histograms (seconds) ``compile/trace_s``, ``compile/lower_s``,
   ``compile/backend_s``: one entry per phase, holding its *self* time;
-- counters ``compile/cache_hits``, ``compile/cache_misses``;
-- histogram ``tune/measure_s``: one entry per tuned key, the whole
-  measurement (the tuner's own compiles count there and nowhere else).
+- counters ``compile/cache_hits``, ``compile/cache_misses``.
 
 Self time keeps the sums from overlapping.  Phases on one thread nest
 (an inner jit traced inside an outer trace; an eager op compiled while
-tracing; the caller waiting for the tuner), and each is reported when
-it ends, so an enclosing phase subtracts every interval that ended
-inside it.  The four sums then add up to no more than the wall time
-they cover.
+tracing), and each is reported when it ends, so an enclosing phase
+subtracts every interval that ended inside it.  The three sums then add
+up to no more than the wall time they cover.
 
 With the tracer on, each owned phase is also a span, recorded after the
 fact (:meth:`repro.obs.trace.Tracer.record`): ``compile/trace``,
-``compile/lower``, ``compile/backend`` (attrs ``fun_name``, ``owner``,
-and ``cache=hit|miss`` on backend spans) and ``tune/measure`` (family,
-numel, c_in, c_out and the winning ``tile_rows``).
+``compile/lower`` and ``compile/backend`` (attrs ``fun_name``,
+``owner``, and ``cache=hit|miss`` on backend spans).
 
 :func:`install` also sets the tracer's ``annotate`` hook to
 ``jax.profiler.TraceAnnotation``: while the tracer is on, every live
@@ -72,9 +65,8 @@ from jax import monitoring
 from repro.obs.metrics import REGISTRY
 from repro.obs.trace import TRACER
 
-__all__ = ["place_compile_cache", "install", "owned", "waiting",
-           "record_tune", "PHASES", "CACHE_COUNTERS", "TUNE_HIST",
-           "EDGES_S"]
+__all__ = ["place_compile_cache", "install", "owned", "PHASES",
+           "CACHE_COUNTERS", "TUNE_HIST", "EDGES_S"]
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -122,6 +114,7 @@ _installed = False
 def _register_metrics() -> None:
     for hist, _ in PHASES.values():
         REGISTRY.histogram(hist, EDGES_S)
+    # never observed: kept so the benchmark's tune_s.setup reads 0.0
     REGISTRY.histogram(TUNE_HIST, EDGES_S)
     for name, _ in CACHE_COUNTERS.values():
         REGISTRY.counter(name)
@@ -174,38 +167,14 @@ def _cover(t0: int, t1: int) -> int:
     return max(0, (t1 - t0) - inner)
 
 
-@contextlib.contextmanager
-def waiting():
-    """The calling thread waits in the scope on another thread's work
-    (the tuner): the wait is taken out of any enclosing owned phase."""
-    if getattr(_local, "owner", None) is None:
-        yield
-        return
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        _cover(t0, time.perf_counter_ns())
-
-
-def record_tune(t0_ns: int, **attrs) -> None:
-    """One tuned key's measurement, begun at ``t0_ns``
-    (``perf_counter_ns``) and ending now."""
-    dur = time.perf_counter_ns() - t0_ns
-    REGISTRY.histogram(TUNE_HIST, EDGES_S).observe(dur / 1e9)
-    TRACER.record("tune/measure", dur, **attrs)
-
-
 def _on_event(event: str, **kwargs) -> None:
     hit = CACHE_COUNTERS.get(event)
     if hit is None:
         return
-    owner = getattr(_local, "owner", None)
-    if owner is None:
+    if getattr(_local, "owner", None) is None:
         return
     name, _local.cache = hit
-    if owner != "tune":
-        REGISTRY.counter(name).inc()
+    REGISTRY.counter(name).inc()
 
 
 def _on_duration(event: str, secs: float, **kwargs) -> None:
@@ -223,6 +192,5 @@ def _on_duration(event: str, secs: float, **kwargs) -> None:
     if span_name == "compile/backend":
         attrs["cache"] = getattr(_local, "cache", None) or "miss"
         _local.cache = None
-    if owner != "tune":
-        REGISTRY.histogram(hist, EDGES_S).observe(self_ns / 1e9)
+    REGISTRY.histogram(hist, EDGES_S).observe(self_ns / 1e9)
     TRACER.record(span_name, dur, **attrs)

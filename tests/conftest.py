@@ -11,14 +11,6 @@ import pytest
 
 REPO_SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-# Pin the tile-sizing heuristic for the suite: measured autotuning times
-# Pallas candidates on first use per key, which is meaningless (and slow)
-# under CPU interpret mode and would re-run for every hypothesis example
-# that clears the plan cache.  The tuner's own tests opt back in with
-# monkeypatch.setenv; benchmark runs (real perf context) leave it on.
-os.environ.setdefault("REPRO_TILE_AUTOTUNE", "0")
-
-
 def run_with_devices(code: str, n_devices: int = 4, timeout: int = 300):
     """Run a python snippet in a subprocess with N fake host devices."""
     env = dict(os.environ)

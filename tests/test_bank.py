@@ -482,6 +482,37 @@ def test_pick_tile_rows_aligned_and_bounded():
             < pick_tile_rows(27, 1, 1, jnp.float32, windows=3))
 
 
+def test_sublanes_cover_itemsize_8():
+    from repro.kernels.melt_stencil import _SUBLANES
+
+    # 32 bytes of sublanes per lane: f64 packs 4 rows, int8 packs 32
+    assert _SUBLANES == {8: 4, 4: 8, 2: 16, 1: 32}
+
+
+@pytest.mark.parametrize("family", ["stencil", "bank", "depthwise"])
+def test_default_tile_interns_nothing_and_measures_nothing(family):
+    """With ``tile_rows`` unset the tile comes from ``pick_tile_rows``
+    alone: a direct kernel call interns no plan and records no
+    ``tune/measure`` span."""
+    from repro import obs
+    from repro.core.grid import make_quasi_grid
+    from repro.kernels import ops
+
+    rng = np.random.RandomState(9)
+    x = jnp.asarray(rng.randn(21, 26).astype(np.float32))
+    grid = make_quasi_grid(x.shape, (3, 3), 1, "same", 1)
+    W = jnp.asarray(rng.randn(9, 2), jnp.float32)
+    call = {"stencil": lambda: ops.fused_stencil(x, grid, W[:, 0]),
+            "bank": lambda: ops.fused_stencil_bank(x, grid, W),
+            "depthwise": lambda: ops.fused_stencil_depthwise(
+                jnp.stack([x, -x], -1), grid, W)}[family]
+    clear_plan_cache()
+    with obs.tracing() as snap:
+        call().block_until_ready()
+    assert plan_cache_stats()["size"] == 0
+    assert not snap().named("tune/measure")
+
+
 def test_tile_rows_override_changes_nothing_numerically():
     from repro.core.grid import make_quasi_grid
     from repro.kernels import ops

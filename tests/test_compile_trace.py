@@ -6,8 +6,6 @@ DESIGN.md §14).
   nested traces count once; compiles with no owner are not counted.
 - ``compile/cache_hits`` / ``compile/cache_misses`` follow JAX's
   persistent cache (a miss, then a hit after ``jax.clear_caches()``).
-- The tile autotuner's time lands in ``tune/measure_s`` and is taken
-  out of the enclosing plan's trace time.
 - With the tracer off only the registry moves; with it on each owned
   phase is a span with its attrs.
 - Under ``jax.profiler``, a ``repro.obs`` span lands on the host plane
@@ -29,7 +27,6 @@ from jax._src import compilation_cache as jax_cc
 from repro import obs
 from repro.core import clear_plan_cache
 from repro.core.plan import get_plan
-from repro.kernels import melt_stencil as ms
 from repro.runtime import compile_cache as cc
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -152,54 +149,6 @@ def test_cache_miss_then_hit(temp_persistent_cache):
     assert second["compile/backend_s"][0] == 1
     caches = [e.attrs["cache"] for e in snap().named("compile/backend")]
     assert caches == ["miss", "hit"]
-
-
-def _outermost(evs):
-    """The spans no other span of ``evs`` contains."""
-    return [e for e in evs
-            if not any(o is not e and o.ts <= e.ts
-                       and e.ts + e.dur <= o.ts + o.dur
-                       and o.dur > e.dur for o in evs)]
-
-
-def test_tuner_time_lands_in_tune_and_leaves_trace(monkeypatch):
-    monkeypatch.setenv("REPRO_TILE_AUTOTUNE", "1")
-    clear_plan_cache()
-    ms._TUNE_MEMO.clear()
-    try:
-        x = jnp.asarray(np.random.default_rng(3).standard_normal(
-            (20, 24)).astype(np.float32))
-        w = jnp.full((9,), 1.0 / 9.0, jnp.float32)
-        plan = get_plan(x.shape, x.dtype, 3, method="fused")
-        before = _reading()
-        t0 = time.perf_counter()
-        with obs.tracing() as snap:
-            plan(x, w).block_until_ready()
-        wall = time.perf_counter() - t0
-        d = _delta(before, _reading())
-    finally:
-        clear_plan_cache()
-        ms._TUNE_MEMO.clear()
-    (tune,) = snap().named("tune/measure")
-    assert tune.attrs["family"] == "stencil" and tune.attrs["numel"] == 9
-    assert tune.attrs["tile_rows"] in ms._tile_candidates(
-        9, 1, 1, jnp.float32)
-    assert d[cc.TUNE_HIST][0] == 1
-    assert d[cc.TUNE_HIST][1] == pytest.approx(tune.dur / 1e9, abs=1e-3)
-    # the plan's trace waited for the tuner; that wait is not trace time
-    outer = _outermost([e for e in snap().named("compile/trace")
-                        if e.attrs["owner"] == "stencil"])
-    assert any(e.attrs["fun_name"] == "run" for e in outer)
-    traced = sum(e.dur for e in outer) / 1e9
-    assert d["compile/trace_s"][1] <= traced - d[cc.TUNE_HIST][1]
-    # the tuner's own compiles count in tune/measure_s only
-    owned = [e for e in snap().named("compile/backend")
-             if e.attrs["owner"] != "tune"]
-    assert d["compile/backend_s"][0] == len(owned)
-    assert any(e.attrs["owner"] == "tune"
-               for e in snap().named("compile/backend"))
-    spent = sum(d[k][1] for k in PHASES + (cc.TUNE_HIST,))
-    assert spent <= wall
 
 
 def test_tracer_off_counts_without_spans():

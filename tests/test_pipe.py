@@ -391,8 +391,7 @@ def test_mixed_plan_kinds_intern_side_by_side(fresh_cache, rng):
     P.run(method="lax", pad_value="edge")                       # PipePlan
     assert plan_cache_stats()["size"] == 4
     assert plan_cache_stats()["kinds"] == {
-        "stencil": 1, "bank": 1, "stats": 1, "pipe": 1, "tile": 0,
-        "tune": 0}
+        "stencil": 1, "bank": 1, "stats": 1, "pipe": 1, "tile": 0}
     plan_cache_reset()  # zero counters, keep the four warm plans
     for _ in range(3):
         P.run(method="lax", pad_value="edge")

@@ -103,7 +103,7 @@ def test_clear_resets_everything():
     s = plan_cache_stats()
     assert s == {"size": 0, "hits": 0, "misses": 0, "evictions": 0,
                  "kinds": {"stencil": 0, "bank": 0, "stats": 0, "pipe": 0,
-                           "tile": 0, "tune": 0}}
+                           "tile": 0}}
 
 
 def test_lru_eviction_bounds_cache(monkeypatch):
