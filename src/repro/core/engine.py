@@ -105,6 +105,8 @@ def _stencil_lax(x, grid: QuasiGrid, weights, pad_value, batched):
 def execute_stencil(x, grid: QuasiGrid, weights, pad_value, method: str,
                     batched: bool = False):
     """Run one resolved stencil problem — shared by plans and direct calls."""
+    support = _fused_support(weights) if method == "fused" else None
+    weights = jnp.asarray(weights)
     if method == "materialize":
         return _stencil_materialize(x, grid, weights, pad_value, batched)
     if method == "lax":
@@ -114,9 +116,23 @@ def execute_stencil(x, grid: QuasiGrid, weights, pad_value, method: str,
 
         return melt_stencil_ops.fused_stencil(
             x, grid, weights, pad_value=normalize_pad_value(pad_value),
-            batched=batched,
+            batched=batched, support=support,
         )
     raise ValueError(f"unknown method {method!r}")
+
+
+def _fused_support(weights):
+    """The static zero pattern of concrete weights, for the fused kernel
+    to skip their zero products (``melt_stencil.weight_support``).  Traced
+    weights, such as a plan executor's argument or an array made inside a
+    trace, run dense (``None``): callers that hold their weights in numpy
+    (the pipe's steps) pass them as such."""
+    if isinstance(weights, jax.core.Tracer):
+        return None
+    from repro.kernels.melt_stencil import weight_support
+
+    W = np.asarray(weights)
+    return weight_support(W.reshape(W.shape[0], -1))
 
 
 # -- operator banks (DESIGN.md §9) -----------------------------------------
@@ -217,6 +233,7 @@ def execute_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value,
         out = melt_stencil_ops.fused_stencil_bank(
             x, grid, W, pad_value=normalize_pad_value(pad_value),
             batched=batched, pointwise=pointwise,
+            support=_fused_support(weight_matrix),
         )
         return (out if pointwise is None
                 else _trailing_channels(out, grid.rank, batched))

@@ -25,7 +25,8 @@ a run of taps whose rows overlap (a 3×3×3 operator over a volume needs
 three windows, one per z-plane, instead of one slab spanning two whole
 planes of halo).  The copy is synchronous; the tile is then swept in
 8-row chunks so the accumulators stay in vector registers.  Weights live
-in SMEM as scalars.
+in SMEM as scalars; only their static zero pattern (``weight_support``)
+shapes the unrolled body, which skips the products of zero weights.
 
 Families.  One kernel serves all three linear families: input
 ``(B, C, rows, 128)``, ``kper`` outputs per input channel, weights
@@ -139,7 +140,34 @@ def plan_windows(offsets: Sequence[int], tile_rows: int):
     return taps, tuple(windows)
 
 
-def _melt_kernel(w_ref, x_hbm, o_ref, slab, sems, *, taps, windows,
+def weight_support(weights, channels: int = 1):
+    """The static zero pattern of a concrete ``(numel, C·kper)`` weight
+    matrix, for :func:`fused_melt_rows`.
+
+    Returns ``None`` when every weight is nonzero somewhere it is read
+    (a dense bank: the kernel runs every product), else a tuple of
+    ``(tap, (k, ...))`` pairs in tap order: the kept taps, and per kept
+    tap the kept columns among ``k ∈ range(kper)``.  A tap whose whole
+    row is zero is left out, and so are its loads.  With ``channels`` C
+    > 1 (the depthwise family) the input channel is a grid index, not a
+    static one, so a product is kept where any channel's weight on it is
+    nonzero: the union over channels.  Only the pattern is static; the
+    weight values stay runtime scalars, so a bank of other values with
+    the same zeros compiles once.  Every tap of a separable pass is
+    nonzero in some column (the CT interior's 9-tap factors, for one), so
+    there a support would drop no load, only a product or two:
+    ``fused_separable_bank`` and ``fused_stencil_depthwise`` take none.
+    """
+    W = np.asarray(weights)
+    numel, wcols = W.shape
+    kept = (W.reshape(numel, channels, wcols // channels) != 0).any(axis=1)
+    if kept.all():
+        return None
+    return tuple((t, tuple(int(k) for k in np.flatnonzero(row)))
+                 for t, row in enumerate(kept) if row.any())
+
+
+def _melt_kernel(w_ref, x_hbm, o_ref, slab, sems, *, support, taps, windows,
                  tile_rows: int, kper: int, wcols: int):
     b, c, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     base = pl.multiple_of(i * tile_rows, _CHUNK)
@@ -158,14 +186,15 @@ def _melt_kernel(w_ref, x_hbm, o_ref, slab, sems, *, taps, windows,
     def chunk(j, carry):
         r0 = pl.multiple_of(j * _CHUNK, _CHUNK)
         accs = [jnp.zeros((_CHUNK, LANES), jnp.float32)] * kper
-        for t, (row, shift) in enumerate(taps):
+        # only the kept (tap, k) products: a column with none stores zeros
+        for (t, ks), (row, shift) in zip(support, taps):
             v = slab[pl.ds(r0 + row, _CHUNK), :]
             if shift:
                 nxt = slab[pl.ds(r0 + row + 1, _CHUNK), :]
                 v = jnp.where(lane < LANES - shift,
                               pltpu.roll(v, LANES - shift, 1),
                               pltpu.roll(nxt, LANES - shift, 1))
-            for k in range(kper):
+            for k in ks:
                 accs[k] = accs[k] + w_ref[t * wcols + wbase + k] * v
         for k in range(kper):
             o_ref[0, k, pl.ds(r0, _CHUNK), :] = accs[k]
@@ -176,7 +205,7 @@ def _melt_kernel(w_ref, x_hbm, o_ref, slab, sems, *, taps, windows,
 
 def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
                     tile_rows: Optional[int] = None,
-                    interpret: bool = True):
+                    interpret: bool = True, support=None):
     """The canonical linear melt pass, every family.
 
     x: ``(B, C, P)`` flat padded volumes, ``P`` a multiple of 128 (whole
@@ -187,6 +216,15 @@ def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
     ``t``.  Returns ``(B, C·kper, P)`` float32.  ``tile_rows=None`` means
     :func:`pick_tile_rows` for these channels; any tile is capped at what
     fits the VMEM budget for this call's window geometry.
+
+    ``support`` (:func:`weight_support`) is the weights' static zero
+    pattern: the kernel unrolls only its kept (tap, k) products, and
+    plans its windows, and so its tile, from the kept taps alone.
+    ``None`` means dense, every product of every tap.  Skipping a zero
+    weight drops a term ``0·v`` from a sum that starts at +0.0 and keeps
+    its other terms in order, so on finite input the output is bitwise
+    the dense one.  The one difference: a NaN or Inf read only through
+    zero weights no longer reaches the output.
     """
     B, C, P = x.shape
     offsets = tuple(int(o) for o in offsets)
@@ -198,10 +236,15 @@ def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
         raise ValueError(f"flat volumes must fill whole {LANES}-lane rows, "
                          f"got {P} positions")
     kper = wcols // C
+    if support is None:
+        support = tuple((t, tuple(range(kper))) for t in range(numel))
+    if not support:  # all-zero weights read nothing
+        return jnp.zeros((B, wcols, P), jnp.float32)
+    kept = [offsets[t] for t, _ in support]
     # the front halo in whole rows, so both pads below are row pads of
     # the (rows, 128) view
-    front = -(-max(0, -min(offsets)) // LANES)
-    shifted = tuple(o + front * LANES for o in offsets)
+    front = -(-max(0, -min(kept)) // LANES)
+    shifted = tuple(o + front * LANES for o in kept)
     if tile_rows is None:
         tile_rows = pick_tile_rows(numel, C, wcols, x.dtype)
     out_rows = P // LANES
@@ -222,8 +265,9 @@ def fused_melt_rows(x: jax.Array, weights: jax.Array, offsets,
     xf = jnp.pad(x.astype(jnp.float32).reshape(B, C, out_rows, LANES),
                  ((0, 0), (0, 0), (front, in_rows - front - out_rows),
                   (0, 0)))
-    kernel = functools.partial(_melt_kernel, taps=taps, windows=windows,
-                               tile_rows=T, kper=kper, wcols=wcols)
+    kernel = functools.partial(_melt_kernel, support=support, taps=taps,
+                               windows=windows, tile_rows=T, kper=kper,
+                               wcols=wcols)
     out = pl.pallas_call(
         kernel,
         grid=(B, C, tiles),

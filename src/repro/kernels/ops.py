@@ -74,14 +74,22 @@ def _flat(xc, grid: QuasiGrid, pad_value):
     return xp.reshape(B, C, -1)
 
 
-def _melt_rows(xc, grid: QuasiGrid, W, pad_value, tile_rows, interpret):
+def _melt_rows(xc, grid: QuasiGrid, W, pad_value, tile_rows, interpret,
+               support=None):
     """(B, C, *spatial) → (B, C·kper, rows, 128) float32: the linear
-    kernel's own output, every position of the padded flat volume."""
+    kernel's own output, every position of the padded flat volume.
+    ``support`` is the weights' static zero pattern
+    (``melt_stencil.weight_support``), ``None`` for dense."""
     _check_fused_grid(grid)
     interpret = _interpret_default() if interpret is None else interpret
-    rows = _ms.fused_melt_rows(_flat(xc, grid, pad_value), jnp.asarray(W),
+    W = jnp.asarray(W)
+    if support is not None:
+        kept = sum(len(ks) for _, ks in support)
+        _counter("kernels/zero_weight_products").inc(
+            W.shape[0] * (W.shape[1] // xc.shape[1]) - kept)
+    rows = _ms.fused_melt_rows(_flat(xc, grid, pad_value), W,
                                grid.flat_offsets(), tile_rows=tile_rows,
-                               interpret=interpret)
+                               interpret=interpret, support=support)
     return rows.reshape(xc.shape[0], rows.shape[1], -1, _ms.LANES)
 
 
@@ -100,46 +108,52 @@ def _crop_rows(rows, grid: QuasiGrid):
     return out[(slice(None),) * len(lead) + _valid_slices(grid)]
 
 
-def _melt(xc, grid: QuasiGrid, W, pad_value, tile_rows, interpret):
+def _melt(xc, grid: QuasiGrid, W, pad_value, tile_rows, interpret,
+          support=None):
     """(B, C, *spatial) → (B, C·kper, *out_shape) float32 through the one
     linear kernel: pad, flatten, every-position pass, crop."""
     return _crop_rows(_melt_rows(xc, grid, W, pad_value, tile_rows,
-                                 interpret), grid)
+                                 interpret, support), grid)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("grid", "pad_value", "interpret", "batched",
-                     "tile_rows"))
+                     "tile_rows", "support"))
 def fused_stencil(x, grid: QuasiGrid, weights, pad_value=0.0,
-                  interpret=None, batched=False, tile_rows=None):
+                  interpret=None, batched=False, tile_rows=None,
+                  support=None):
     """Rank-agnostic fused melt×contract (stride-1 'same'/'valid' grids).
 
     ``batched=True``: leading dim of ``x`` is a stack of independent tensors;
     the Pallas grid gains a batch axis (one kernel launch for the stack).
     ``tile_rows=None`` sizes the tile from the VMEM budget
-    (``pick_tile_rows``, DESIGN.md §16).
+    (``pick_tile_rows``, DESIGN.md §16).  ``support`` is the static zero
+    pattern of the weights as one column (``melt_stencil.weight_support``):
+    the kernel skips the zero taps, and ``None`` runs every tap.
     """
     xb = x if batched else x[None]
     out = _melt(xb[:, None], grid, jnp.reshape(weights, (-1, 1)), pad_value,
-                tile_rows, interpret)[:, 0]
+                tile_rows, interpret, support)[:, 0]
     return (out if batched else out[0]).astype(x.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("grid", "pad_value", "interpret", "batched",
-                     "tile_rows", "pointwise"))
+                     "tile_rows", "pointwise", "support"))
 def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
                        interpret=None, batched=False, tile_rows=None,
-                       pointwise=None):
+                       pointwise=None, support=None):
     """K operators over one melt pass: (..., *spatial) → (..., *spatial, K).
 
     ``weight_matrix`` is (numel(m), K); each grid step reads the input
     windows of one output tile once and accumulates all K operators from
     them, so the window load is amortized across the bank and ``M``
     never exists in HBM.  ``tile_rows=None`` sizes the tile from the
-    VMEM budget (``pick_tile_rows``, DESIGN.md §16).
+    VMEM budget (``pick_tile_rows``, DESIGN.md §16).  ``support`` is the
+    matrix's static zero pattern (``melt_stencil.weight_support``): the
+    kernel runs only its nonzero products, and ``None`` runs them all.
 
     ``pointwise`` is an elementwise function of channel-major values,
     (..., K, *spatial) → (..., [K',] *spatial).  It runs on the kernel's
@@ -151,11 +165,11 @@ def fused_stencil_bank(x, grid: QuasiGrid, weight_matrix, pad_value=0.0,
     xb = x if batched else x[None]
     if pointwise is None:
         out = _melt(xb[:, None], grid, weight_matrix, pad_value, tile_rows,
-                    interpret)
+                    interpret, support)
         out = jnp.moveaxis(out, 1, -1)
         return (out if batched else out[0]).astype(x.dtype)
     rows = _melt_rows(xb[:, None], grid, weight_matrix, pad_value,
-                      tile_rows, interpret).astype(x.dtype)
+                      tile_rows, interpret, support).astype(x.dtype)
     B, K, R = rows.shape[:3]
     flat = ((R * _ms.LANES,) if grid.rank == 1
             else (1,) * (grid.rank - 2) + (R, _ms.LANES))

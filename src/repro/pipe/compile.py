@@ -126,11 +126,10 @@ def _apply_linear(h, step: LinearStep, opts: ExecOptions, batched: bool):
         return out[..., 0] if step.kind == "stencil" else out
     if step.kind == "stencil":
         return engine.execute_stencil(
-            h, step.grid, jnp.asarray(step.weights[:, 0]), opts.pad_value,
-            meth, batched)
+            h, step.grid, step.weights[:, 0], opts.pad_value, meth, batched)
     return engine.execute_stencil_bank(
-        h, step.grid, jnp.asarray(step.weights), opts.pad_value, meth,
-        batched, pointwise=step.pointwise)
+        h, step.grid, step.weights, opts.pad_value, meth, batched,
+        pointwise=step.pointwise)
 
 
 def _apply_pointwise(h, step, batched: bool, rank: int):

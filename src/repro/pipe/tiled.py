@@ -211,10 +211,9 @@ def _tile_linear(h, step: LinearStep, dim_pads, opts: ExecOptions,
         return out[..., 0] if step.kind == "stencil" else out
     if step.kind == "stencil":
         return engine.execute_stencil(
-            h, lgrid, jnp.asarray(step.weights[:, 0]), 0.0, meth, batched)
-    return engine.execute_stencil_bank(
-        h, lgrid, jnp.asarray(step.weights), 0.0, meth, batched,
-        pointwise=step.pointwise)
+            h, lgrid, step.weights[:, 0], 0.0, meth, batched)
+    return engine.execute_stencil_bank(h, lgrid, step.weights, 0.0, meth,
+                                       batched, pointwise=step.pointwise)
 
 
 def _tile_zscore(h, step: ZscoreStep, dim_pads, opts: ExecOptions,

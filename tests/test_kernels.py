@@ -112,3 +112,136 @@ def test_depthwise_matches_model_layer():
     got = ops.depthwise_conv1d(x, w)
     want, _ = causal_depthwise_conv1d(x, w)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+# -- zero-weight skipping (melt_stencil.weight_support) ----------------------
+
+from repro.core import engine  # noqa: E402
+from repro.core.filters import curvature_bank, difference_stencils  # noqa: E402
+from repro.kernels import melt_stencil as ms  # noqa: E402
+from repro.obs.metrics import counter  # noqa: E402
+
+
+def _dyadic(rng, shape):
+    """Random signed powers of two.  The CPU backend fuses a multiply and
+    an add into one FMA where it likes, differently from one program to
+    the next; with exact products the fused and unfused sums agree, so a
+    bitwise comparison sees only which terms are summed and in what
+    order.  The derivative banks' weights are powers of two already."""
+    return (np.where(rng.rand(*shape) < 0.5, -1.0, 1.0)
+            * 2.0 ** rng.randint(-3, 4, shape)).astype(np.float32)
+
+
+def _sparse_bank(rng, K=5):
+    """A random bank with one zero row and scattered zero entries."""
+    W = _dyadic(rng, (27, K))
+    W[4] = 0.0
+    W[rng.rand(27, K) < 0.4] = 0.0
+    return W
+
+
+def _zero_column(rng):
+    W = _dyadic(rng, (27, 4))
+    W[:, 2] = 0.0
+    return W
+
+
+SPARSE_BANKS = {
+    "curvature2": lambda rng: curvature_bank(2),
+    "curvature3": lambda rng: curvature_bank(3),
+    "gradient3": lambda rng: difference_stencils(3)[0].astype(np.float32),
+    "sparse": _sparse_bank,
+    "zero-column": _zero_column,
+    "all-zero": lambda rng: np.zeros((27, 3), np.float32),
+}
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["one", "batched"])
+@pytest.mark.parametrize("padding", ["same", "valid"])
+@pytest.mark.parametrize("bank", list(SPARSE_BANKS))
+def test_support_is_bitwise_dense(bank, padding, batched):
+    """Skipping the zero products leaves the bank's output bitwise equal
+    to the dense kernel's on finite input, in every column."""
+    rng = np.random.RandomState(19)
+    W = SPARSE_BANKS[bank](rng)
+    rank = round(np.log(W.shape[0]) / np.log(3))
+    spatial = (6, 9, 20)[-rank:]
+    x = jnp.asarray(rng.randn(*(((2,) if batched else ()) + spatial)),
+                    jnp.float32)
+    grid = make_quasi_grid(spatial, (3,) * rank, 1, padding, 1)
+    support = ms.weight_support(W)
+    assert support is not None
+    kw = dict(pad_value="edge" if padding == "same" else 0.0,
+              batched=batched)
+    dense = ops.fused_stencil_bank(x, grid, W, **kw)
+    got = ops.fused_stencil_bank(x, grid, W, support=support, **kw)
+    np.testing.assert_array_equal(_bits(got), _bits(dense))
+    # the stencil family: one column, its zero taps skipped
+    w = W[:, 0]
+    dense = ops.fused_stencil(x, grid, w, **kw)
+    got = ops.fused_stencil(x, grid, w, support=ms.weight_support(w[:, None]),
+                            **kw)
+    np.testing.assert_array_equal(_bits(got), _bits(dense))
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+def test_depthwise_support_is_the_union_over_channels(batch):
+    """With C > 1 channels a product is kept where any channel's weight
+    is nonzero; the kernel then matches the dense one bitwise."""
+    rng = np.random.RandomState(23)
+    C, shape = 3, (6, 9, 20)
+    W = _dyadic(rng, (27, C)) * (rng.rand(27, C) < 0.3)
+    support = ms.weight_support(W, channels=C)
+    union = np.flatnonzero((W != 0).any(axis=1))
+    assert [t for t, _ in support] == list(union)
+    assert all(ks == (0,) for _, ks in support)
+    offsets = make_quasi_grid(shape, (3, 3, 3), 1, "same", 1).flat_offsets()
+    x = jnp.asarray(rng.randn(batch, C, 8 * ms.LANES), jnp.float32)
+    dense = ms.fused_melt_rows(x, W, offsets)
+    got = ms.fused_melt_rows(x, W, offsets, support=support)
+    np.testing.assert_array_equal(_bits(got), _bits(dense))
+
+
+def test_dense_weights_have_no_support():
+    """A bank with no zero product keeps today's kernel: no support."""
+    assert ms.weight_support(
+        np.asarray(gaussian_weights((3, 3, 3), 1.0)).reshape(27, 1)) is None
+    assert ms.weight_support(np.ones((9, 4), np.float32)) is None
+
+
+@pytest.mark.parametrize("bank,skipped", [
+    ("curvature3", 285), ("gradient3", 75), ("gaussian", 0)])
+def test_zero_weight_products_counter(bank, skipped):
+    """``kernels/zero_weight_products`` counts the products a build
+    skips: dense products less kept ones."""
+    rng = np.random.RandomState(5)
+    W = (np.asarray(gaussian_weights((3, 3, 3), 1.0)).reshape(27, 1)
+         if bank == "gaussian" else SPARSE_BANKS[bank](rng))
+    x = jnp.asarray(rng.randn(6, 9, 20), jnp.float32)
+    grid = make_quasi_grid(x.shape, (3, 3, 3), 1, "same", 1)
+    c = counter("kernels/zero_weight_products")
+    before = c.value
+    got = engine.execute_stencil_bank(x, grid, W, "edge", "fused")
+    assert c.value - before == skipped
+    want = engine.execute_stencil_bank(x, grid, W, "edge", "lax")
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_traced_weights_run_dense():
+    """Weights passed as a jit argument are traced: their zeros are not
+    static, so the kernel runs every product and still agrees with lax."""
+    rng = np.random.RandomState(6)
+    W = curvature_bank(3)
+    x = jnp.asarray(rng.randn(6, 9, 20), jnp.float32)
+    grid = make_quasi_grid(x.shape, (3, 3, 3), 1, "same", 1)
+    c = counter("kernels/zero_weight_products")
+    before = c.value
+    got = jax.jit(lambda w: engine.execute_stencil_bank(
+        x, grid, w, "edge", "fused"))(jnp.asarray(W))
+    assert c.value == before
+    want = engine.execute_stencil_bank(x, grid, W, "edge", "lax")
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)

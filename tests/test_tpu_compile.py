@@ -32,6 +32,8 @@ CT = (256, 512, 512)
 #: one chip's slab of the four-chip RM state: 80 slices and a 3-plane
 #: halo on each side, the 2048×2048 plane padded for a 7-tap Gaussian
 RM_SLAB = (86, 2054, 2054)
+#: the same slab as the 3×3×3 gradient bank reads it: a 1-plane halo
+RM_GRAD_SLAB = (82, 2050, 2050)
 TILE_ROWS = 256
 #: argument + output + temp bytes over the call's own input + output
 #: bytes; a one-lane operand relaid out to 128 lanes would be ~128
@@ -170,3 +172,27 @@ def test_curvature_handoff_relayouts_one_channel(one_chip):
                for line in text.splitlines() if " while(" in line
                for dims in re.findall(r"f32\[([\d,]+)\]", line)]
     assert carried and max(carried) <= 1.2 * padded, max(carried) / padded
+
+
+@pytest.mark.parametrize("shape,bank,padding", [
+    (CT, "curvature", "same"),
+    (RM_GRAD_SLAB, "gradient", "valid"),
+], ids=["ct-curvature", "rm-slab-gradient"])
+def test_bank_with_support_compiles(one_chip, shape, bank, padding):
+    """A derivative bank with its zero products skipped: the K=12
+    curvature bank at CT size, and the K=3 gradient bank on one chip's
+    RM slab, both unroll only their kept (tap, k) products."""
+    from repro.core.filters import difference_stencils
+    from repro.kernels.melt_stencil import weight_support
+
+    W = (curvature_bank(3) if bank == "curvature"
+         else difference_stencils(3)[0].astype(np.float32))
+    support = weight_support(W)
+    assert support is not None
+    K = W.shape[1]
+    grid = make_quasi_grid(shape, (3, 3, 3), 1, padding, 1)
+    lowered = ops.fused_stencil_bank.lower(
+        _sds(shape, one_chip), grid=grid, weight_matrix=_sds((27, K), one_chip),
+        pad_value="edge" if padding == "same" else 0.0, interpret=False,
+        support=support)
+    _check(lowered, shape, grid.out_shape + (K,))
